@@ -1,7 +1,9 @@
-"""CRAM-KV kernel micro-bench: pack/unpack/fused-attention timings (CPU
-interpret mode — structural, not TPU wall-clock) + the bandwidth savings on
-compressible vs incompressible KV streams, plus the checkpoint codec's
-compression ratio per tensor class (the Fig. 4 story on our own data)."""
+"""CRAM-KV kernel micro-bench: pack/unpack/fused-attention timings + the
+bandwidth savings on compressible vs incompressible KV streams, plus the
+checkpoint codec's compression ratio per tensor class (the Fig. 4 story on
+our own data).  Kernels run as `kernels.resolve_interpret` decides:
+compiled on a TPU, interpreted on the CPU, where timings are structural
+only and no device metric."""
 
 from __future__ import annotations
 
@@ -33,8 +35,9 @@ def blockspec_sweep(*, batch=4, n_groups=8, page=8, hkv=1, d=32,
     look fast.  CI runs this (`--sweep kernels`) and fails on any parity
     row; the committed snapshot is BENCH_kernels.json.
 
-    Timings are CPU interpret-mode — structural (relative cost of the
-    tilings and the fused-vs-reference gap), not TPU wall-clock."""
+    On the CPU the kernels are interpreted and the timings are
+    structural (relative cost of the tilings and the fused-vs-reference
+    gap); on a TPU they time the compiled kernels.  `backend` names which."""
     rng = np.random.default_rng(seed)
     d2 = 2 * d
 
@@ -49,7 +52,9 @@ def blockspec_sweep(*, batch=4, n_groups=8, page=8, hkv=1, d=32,
                           .view(jnp.int16))
 
     report: dict = {"batch": batch, "n_groups": n_groups, "page": page,
-                    "n_kv": hkv, "head_dim": d, "modes": {}}
+                    "n_kv": hkv, "head_dim": d, "modes": {},
+                    "backend": {"platform": jax.devices()[0].platform,
+                                "device_kind": jax.devices()[0].device_kind}}
     for lanes in (2, 4):
         build = (ops.build_cram_cache if lanes == 2
                  else ops.build_cram_cache_quad)
@@ -76,7 +81,7 @@ def blockspec_sweep(*, batch=4, n_groups=8, page=8, hkv=1, d=32,
         rows, best = [], None
         for bg in (1, 2, 4, n_groups, None):
             out, raw_s, cram_s = ops.decode_attention_fused(
-                q, cache, vp, lanes=lanes, block_groups=bg, interpret=True)
+                q, cache, vp, lanes=lanes, block_groups=bg)
             err = float(np.max(np.abs(np.asarray(out, np.float32) - ref)))
             bytes_ok = (np.array_equal(np.asarray(raw_s),
                                        bw["raw_per_seq"])
@@ -84,8 +89,8 @@ def blockspec_sweep(*, batch=4, n_groups=8, page=8, hkv=1, d=32,
                                            bw["cram_per_seq"]))
             us = _timeit(lambda qq, lanes=lanes, bg=bg:
                          ops.decode_attention_fused(
-                             qq, cache, vp, lanes=lanes, block_groups=bg,
-                             interpret=True)[0], q, n=n_timing)
+                             qq, cache, vp, lanes=lanes,
+                             block_groups=bg)[0], q, n=n_timing)
             row = {"block_groups": bg, "us_per_call": round(us, 1),
                    "max_err_vs_oracle": err,
                    "numerics_parity": err < 2e-2,

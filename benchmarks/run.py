@@ -387,8 +387,10 @@ def run_sweep(args) -> None:
     print(f"report -> {out_path}")
 
 
-def run_legacy(only) -> None:
-    all_rows = []
+def run_legacy(only) -> list:
+    """Run the per-figure modules; returns the names of those that raised
+    (each still reports an ERROR row and the suite keeps going)."""
+    all_rows, failed = [], []
     print("name,us_per_call,derived")
     for mod_name in MODULES:
         if only and mod_name not in only:
@@ -399,6 +401,7 @@ def run_legacy(only) -> None:
             rows = mod.run()
         except Exception as e:  # keep the suite running
             traceback.print_exc()
+            failed.append(mod_name)
             rows = [(f"{mod_name}/ERROR", 0.0, repr(e)[:100])]
         for name, us, derived in rows:
             print(f"{name},{us:.1f},{derived}")
@@ -409,14 +412,16 @@ def run_legacy(only) -> None:
     out = _ROOT / "experiments"
     out.mkdir(exist_ok=True)
     (out / "bench_results.json").write_text(json.dumps(all_rows, indent=1))
+    return failed
 
 
 def main() -> None:
     import os
 
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("modules", nargs="*",
-                    help="legacy mode: per-figure modules to run")
+    ap.add_argument("modules", nargs="*", choices=MODULES, metavar="MODULE",
+                    help="legacy mode: per-figure modules to run "
+                         f"({', '.join(MODULES)})")
     ap.add_argument("--sweep",
                     choices=("all", "memsim", "compress", "serve", "codecs",
                              "policy", "serve-spill", "kernels"),
@@ -439,6 +444,9 @@ def main() -> None:
                          "else; non-zero exit on violations or golden "
                          "drift")
     args = ap.parse_args()
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.analyze:
         from repro.analysis.__main__ import main as analysis_main
 
@@ -460,7 +468,9 @@ def main() -> None:
         if given:
             ap.error(f"{', '.join(given)} require(s) --sweep; legacy mode "
                      "is configured via $REPRO_BENCH_EVENTS")
-        run_legacy(args.modules or None)
+        failed = run_legacy(args.modules or None)
+        if failed:
+            raise SystemExit(f"benchmark modules failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -1,7 +1,9 @@
 """Serve a small model with batched requests + CRAM-KV accounting.
 
 Every sequence in the batch streams through the batched incremental
-CRAM-KV cache (one attention layer's real decode traffic).
+CRAM-KV cache (one attention layer's real decode traffic).  The model is
+the `--arch` configuration shrunk to CPU size (`--smoke`); run
+`python -m repro.launch.serve --arch ...` for the published width.
 
   PYTHONPATH=src python examples/serve_lm.py [--arch phi4_mini_3_8b]
 """
@@ -19,6 +21,6 @@ if __name__ == "__main__":
     ap.add_argument("--kv-policy", default="dynamic",
                     choices=["dynamic", "static", "off"])
     args = ap.parse_args()
-    serve_main(["--arch", args.arch, "--batch", str(args.batch),
+    serve_main(["--arch", args.arch, "--smoke", "--batch", str(args.batch),
                 "--gen", str(args.gen), "--prompt-len",
                 str(args.prompt_len), "--kv-policy", args.kv_policy])

@@ -49,21 +49,21 @@ PINNED_PRIMITIVES = CALLBACK_PRIMITIVES + ("pallas_call", "scan", "while",
 
 def _walk(jaxpr, counts: Counter) -> Counter:
     """Recursive primitive histogram (descends into closed sub-jaxprs)."""
-    import jax
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     for eqn in jaxpr.eqns:
         counts[eqn.primitive.name] += 1
         for v in eqn.params.values():
             for item in (v if isinstance(v, (tuple, list)) else (v,)):
-                if isinstance(item, jax.core.ClosedJaxpr):
+                if isinstance(item, ClosedJaxpr):
                     _walk(item.jaxpr, counts)
-                elif isinstance(item, jax.core.Jaxpr):
+                elif isinstance(item, Jaxpr):
                     _walk(item, counts)
     return counts
 
 
 def _dtypes(jaxpr, acc: set) -> set:
-    import jax
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     for v in list(jaxpr.invars) + list(jaxpr.outvars) + list(
             jaxpr.constvars):
@@ -75,9 +75,9 @@ def _dtypes(jaxpr, acc: set) -> set:
                 acc.add(str(v.aval.dtype))
         for p in eqn.params.values():
             for item in (p if isinstance(p, (tuple, list)) else (p,)):
-                if isinstance(item, jax.core.ClosedJaxpr):
+                if isinstance(item, ClosedJaxpr):
                     _dtypes(item.jaxpr, acc)
-                elif isinstance(item, jax.core.Jaxpr):
+                elif isinstance(item, Jaxpr):
                     _dtypes(item, acc)
     return acc
 

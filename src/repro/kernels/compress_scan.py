@@ -34,6 +34,8 @@ from repro.compression.framing import (DEFAULT_MARKER_KEY, HEADER_BYTES,
                                        IL_MULT, LINE_BYTES, M2_MULT, M4_MULT)
 from repro.compression.marker import LineStatus
 
+from . import resolve_interpret
+
 WORDS_PER_LINE = 16
 BLOCK_LINES = 256
 
@@ -135,15 +137,24 @@ def _fpc_bytes_i32(w):
     nz_bits = jnp.where(zero, 0, 3 + bits)
     total = nz_bits.sum(axis=-1)
 
-    # zero runs: a run of length L costs ceil(L/8) chunks of (3+3) bits
-    prev = jnp.concatenate(
-        [jnp.zeros(zero.shape[:-1] + (1,), bool), zero[..., :-1]], axis=-1)
-    starts = zero & ~prev
-    run_id = jnp.cumsum(starts.astype(jnp.int32), axis=-1)
-    chunks = jnp.zeros(zero.shape[:-1], jnp.int32)
-    for k in range(1, WORDS_PER_LINE + 1):
-        len_k = (zero & (run_id == k)).sum(axis=-1)
-        chunks = chunks + (len_k + 7) // 8 * (len_k > 0)
+    # zero runs: a run of length L costs ceil(L/8) chunks of (3+3) bits,
+    # i.e. one chunk per zero word at offset 0, 8, 16, ... into its run.
+    # Counted with static shifts (Mosaic has no cumsum) on 0/1 int32 flags
+    # (it cannot concatenate bool vectors): word p opens a chunk iff it is
+    # zero and the run holding it started at p - 8k.
+    def shifted(x, k):              # x[..., p - k], 0 before the line
+        pad = jnp.zeros(x.shape[:-1] + (k,), x.dtype)
+        return jnp.concatenate([pad, x[..., :-k]], axis=-1)
+
+    z = zero.astype(jnp.int32)
+    starts = z & (1 - shifted(z, 1))
+    opens = starts
+    run8 = z                        # words p-8k .. p all zero
+    for k in range(8, WORDS_PER_LINE, 8):
+        for s in range(k - 7, k + 1):
+            run8 = run8 & shifted(z, s)
+        opens = opens | (run8 & shifted(starts, k))
+    chunks = opens.sum(axis=-1)
     return (total + chunks * 6 + 7) // 8
 
 
@@ -184,12 +195,19 @@ def _pick(sel, e):
     return jnp.where(ids == sel[..., None], e, 0).sum(axis=-1)
 
 
+def _first_true(x):
+    """Index of the first True along the last axis (its length if none):
+    argmax as a masked min, which Mosaic lowers."""
+    ids = jax.lax.broadcasted_iota(jnp.int32, x.shape, len(x.shape) - 1)
+    return jnp.where(x, ids, x.shape[-1]).min(axis=-1)
+
+
 def _bdi_fits_small(e, wrap_bits, d):
     """fits for b<=4 modes; e: (B, k) int32 elements (sign-extended)."""
     imm = _fits_i32(e, d)
     nonimm = ~imm
     any_non = nonimm.any(axis=-1)
-    fi = jnp.argmax(nonimm, axis=-1)
+    fi = _first_true(nonimm)
     base = jnp.where(any_non, _pick(fi, e), 0)
     delta = e - base[..., None]
     if wrap_bits < 32:                     # wrap into the element width
@@ -203,7 +221,7 @@ def _bdi_fits_b8(lo, hi, d):
     imm = _fits_i64(hi, lo, d)
     nonimm = ~imm
     any_non = nonimm.any(axis=-1)
-    fi = jnp.argmax(nonimm, axis=-1)
+    fi = _first_true(nonimm)
     blo = jnp.where(any_non, _pick(fi, lo), 0)
     bhi = jnp.where(any_non, _pick(fi, hi), 0)
     dlo = lo - blo[..., None]
@@ -212,13 +230,17 @@ def _bdi_fits_b8(lo, hi, d):
     return (imm | _fits_i64(dhi, dlo, d)).all(axis=-1)
 
 
-def _bdi_bytes_i32(w):
-    """Best BDI payload size; w: (B, 16) int32. Mirrors bdi.bdi_sizes."""
+def _bdi_bytes_i32(w, w_eo):
+    """Best BDI payload size; w: (B, 16) int32, w_eo: the same words with
+    the even ones first (the low and high halves of the 8-byte elements:
+    a lane-strided slice is a gather Mosaic cannot lower).  Mirrors
+    bdi.bdi_sizes."""
     e4 = w                                                    # (B, 16)
     lo16 = ((w & 0xFFFF) ^ 0x8000) - 0x8000
     hi16 = (((w >> 16) & 0xFFFF) ^ 0x8000) - 0x8000
     e2 = jnp.stack([lo16, hi16], axis=-1).reshape(*w.shape[:-1], 32)
-    lo8, hi8 = w[..., 0::2], w[..., 1::2]                     # (B, 8)
+    half = WORDS_PER_LINE // 2
+    lo8, hi8 = w_eo[..., :half], w_eo[..., half:]             # (B, 8)
 
     best = jnp.full(w.shape[:-1], LINE_BYTES, jnp.int32)
     for b, d, payload in _BDI_MODES:
@@ -244,7 +266,7 @@ def _classify_i32(w, slot_idx, key: int):
     m4 = two * _M4_I32 + key
     j = jax.lax.broadcasted_iota(jnp.int32, w.shape, len(w.shape) - 1)
     il = ((slot_idx[..., None] * WORDS_PER_LINE + j + 1) * _IL_I32 + key)
-    tail = w[..., -1]
+    tail = _pick(jnp.full(w.shape[:-1], WORDS_PER_LINE - 1, jnp.int32), w)
     is_il = (w == il).all(axis=-1)
     inv = (tail == ~m2) | (tail == ~m4) | (w == ~il).all(axis=-1)
     out = jnp.full(w.shape[:-1], int(LineStatus.UNCOMP), jnp.int32)
@@ -255,18 +277,18 @@ def _classify_i32(w, slot_idx, key: int):
     return out
 
 
-def _scan_kernel(words_ref, sizes_ref, fpc_ref, bdi_ref, status_ref, *, key):
+def _scan_kernel(words_ref, words_eo_ref, sizes_ref, fpc_ref, bdi_ref,
+                 status_ref, *, key):
     blk = words_ref.shape[0]
     w = words_ref[...]
     base = pl.program_id(0) * blk
-    slot = base + jax.lax.broadcasted_iota(jnp.int32, (blk, 1), 0)[:, 0]
+    slot = base + jax.lax.broadcasted_iota(jnp.int32, (blk,), 0)
     fpc = _fpc_bytes_i32(w)
-    bdi = _bdi_bytes_i32(w)
+    bdi = _bdi_bytes_i32(w, words_eo_ref[...])
     hybrid = jnp.minimum(jnp.minimum(fpc, bdi), LINE_BYTES) + HEADER_BYTES
-    sizes_ref[...] = hybrid
-    fpc_ref[...] = fpc
-    bdi_ref[...] = bdi
-    status_ref[...] = _classify_i32(w, slot, key)
+    for ref, val in ((sizes_ref, hybrid), (fpc_ref, fpc), (bdi_ref, bdi),
+                     (status_ref, _classify_i32(w, slot, key))):
+        ref[...] = val.reshape(ref.shape)
 
 
 @functools.partial(jax.jit,
@@ -275,20 +297,20 @@ def _scan_call(words, *, key, block, interpret):
     n = words.shape[0]
     grid = n // block
     spec = pl.BlockSpec((block, WORDS_PER_LINE), lambda i: (i, 0))
-    out_spec = pl.BlockSpec((block,), lambda i: (i,))
-    out = jax.ShapeDtypeStruct((n,), jnp.int32)
-    return pl.pallas_call(
+    # per-line outputs as (blocks, 1, block): a block's minor dims then
+    # equal the array's, whatever tile XLA picks for a long 1-D array
+    out_spec = pl.BlockSpec((1, 1, block), lambda i: (i, 0, 0))
+    out = jax.ShapeDtypeStruct((grid, 1, block), jnp.int32)
+    words_eo = jnp.concatenate([words[:, 0::2], words[:, 1::2]], axis=1)
+    outs = pl.pallas_call(
         functools.partial(_scan_kernel, key=key),
         grid=(grid,),
-        in_specs=[spec],
+        in_specs=[spec, spec],
         out_specs=(out_spec,) * 4,
         out_shape=(out,) * 4,
         interpret=interpret,
-    )(words)
-
-
-def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    )(words, words_eo)
+    return tuple(o.reshape(n) for o in outs)
 
 
 def compress_scan(lines, *, key: int = DEFAULT_MARKER_KEY, block: int = BLOCK_LINES,
@@ -303,8 +325,7 @@ def compress_scan(lines, *, key: int = DEFAULT_MARKER_KEY, block: int = BLOCK_LI
       bdi    — best BDI payload size in bytes (no header)
       status — marker classification (core/marker.LineStatus values)
     """
-    if interpret is None:
-        interpret = default_interpret()
+    interpret = resolve_interpret(interpret)
     key = _as_i32(key & 0xFFFFFFFF)
     lines = np.ascontiguousarray(np.asarray(lines, dtype=np.uint8))
     n = lines.shape[0]

@@ -15,8 +15,9 @@ bytes-moved the kernel measured for exactly the layout it walked —
 `decode_attention_quad_batched` are thin aliases that drop the bytes.
 `hbm_bytes_moved` is the standalone jitted, lanes-aware bandwidth
 reduction (same model, incl. the LLP-mispredict re-probe): the kernel
-byte output matches it bit-exactly (pinned by tests).  All kernels
-default to interpret mode off-TPU.
+byte output matches it bit-exactly (pinned by tests).  `interpret=None`
+resolves through `kernels.resolve_interpret` (interpreted on the CPU
+backend only).
 """
 
 from __future__ import annotations
@@ -35,16 +36,11 @@ from ..compression.framing import DEFAULT_MARKER_KEY
 from .ref import MARKER_LANES, marker_to_lanes, slot_markers
 
 
-def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _pack_all(pages, markers_i16, *, interpret=True):
+def _pack_all(pages, markers_i16, *, interpret=None):
     """pages: (2n, page, Hkv, D2) int16 -> (slots, strips, packed_mask)."""
     a, b = pages[0::2], pages[1::2]
-    packed, base, ok = jax.vmap(
-        lambda x, y: pack_pair(x, y, interpret=interpret))(a, b)
+    packed, base, ok = pack_pair(a, b, interpret=interpret)
     slots = jnp.where(ok[:, None, None, None], packed, a)
     n, _, hkv, d2 = slots.shape
     strips = jnp.zeros((n, hkv, d2 + MARKER_LANES), jnp.int16)
@@ -65,8 +61,6 @@ def build_cram_cache(pages, *, key: int = DEFAULT_MARKER_KEY, interpret=None):
     restricted mapping).  Here the second page of a non-fitting pair is
     stored raw in the *next* slot, mirroring the uncompressed layout.
     """
-    if interpret is None:
-        interpret = default_interpret()
     n2, page, hkv, d2 = pages.shape
     assert n2 % 2 == 0
     markers = slot_markers(n2 // 2, key)
@@ -86,7 +80,7 @@ def build_cram_cache(pages, *, key: int = DEFAULT_MARKER_KEY, interpret=None):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def pack_window(a, b, marker_lanes, enabled, *, interpret=True):
+def pack_window(a, b, marker_lanes, enabled, *, interpret=None):
     """Incrementally (re)pack a gathered window of dirty page pairs.
 
     a/b: (B, W, page, Hkv, D2) int16 — pageA/pageB of each dirty pair;
@@ -101,8 +95,7 @@ def pack_window(a, b, marker_lanes, enabled, *, interpret=True):
 
     Returns (slots, overflow, strips, layout_packed (B, W), fit (B, W)).
     """
-    packed, base, fit = jax.vmap(jax.vmap(
-        lambda x, y: pack_pair(x, y, interpret=interpret)))(a, b)
+    packed, base, fit = pack_pair(a, b, interpret=interpret)
     bsz, w, _, hkv, d2 = a.shape
     lay = fit & enabled[:, None]
     sel = lay[:, :, None, None, None]
@@ -133,7 +126,7 @@ def raw_window(a, b):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def pack_quad_window(pages, marker_lanes, enabled, *, interpret=True):
+def pack_quad_window(pages, marker_lanes, enabled, *, interpret=None):
     """Incrementally (re)pack a gathered window of dirty page QUADS.
 
     pages: (B, W, 4, page, Hkv, D2) int16 — the four lanes of each dirty
@@ -145,9 +138,7 @@ def pack_quad_window(pages, marker_lanes, enabled, *, interpret=True):
     Returns (slots, overflow (B, W, 3, ...), strips, layout_packed, fit).
     """
     a, b, c, d = (pages[:, :, j] for j in range(4))
-    packed, base, fit = jax.vmap(jax.vmap(
-        lambda w, x, y, z: pack_quad(w, x, y, z, interpret=interpret)))(
-        a, b, c, d)
+    packed, base, fit = pack_quad(a, b, c, d, interpret=interpret)
     bsz, w = a.shape[:2]
     hkv, d2 = a.shape[-2:]
     lay = fit & enabled[:, None]
@@ -176,7 +167,7 @@ def raw_quad_window(pages):
     return pages[:, :, 0], pages[:, :, 1:], strips, none, none
 
 
-def layout_window(win, marker_lanes, enabled, *, use_pack, interpret=True):
+def layout_window(win, marker_lanes, enabled, *, use_pack, interpret=None):
     """Dispatch one gathered dirty window to the right layout kernel.
 
     win: (B, W, lanes, page, Hkv, D2) int16 — lanes (2 or 4) selects the
@@ -198,12 +189,10 @@ def layout_window(win, marker_lanes, enabled, *, use_pack, interpret=True):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _pack_all_quad(pages, markers_i16, *, interpret=True):
+def _pack_all_quad(pages, markers_i16, *, interpret=None):
     """pages: (4n, page, Hkv, D2) int16 -> (slots, overflow, strips, ok)."""
     a, b, c, d = pages[0::4], pages[1::4], pages[2::4], pages[3::4]
-    packed, base, ok = jax.vmap(
-        lambda w, x, y, z: pack_quad(w, x, y, z, interpret=interpret))(
-        a, b, c, d)
+    packed, base, ok = pack_quad(a, b, c, d, interpret=interpret)
     sel = ok[:, None, None, None]
     slots = jnp.where(sel, packed, a)
     over_pages = jnp.stack([b, c, d], axis=1)         # (n, 3, page, ...)
@@ -228,8 +217,6 @@ def build_cram_cache_quad(pages, *, key: int = DEFAULT_MARKER_KEY, interpret=Non
     """
     from ..compression.framing import DOMAIN_QUAD
 
-    if interpret is None:
-        interpret = default_interpret()
     n4 = pages.shape[0]
     assert n4 % 4 == 0
     markers = slot_markers(n4 // 4, key, domain=DOMAIN_QUAD)
@@ -397,8 +384,6 @@ def decode_attention_fused(q, cache, valid_per_page, predictor=None, *,
     same masks — measured by the kernel for the layout it walked, not by
     a second pass over the state.
     """
-    if interpret is None:               # static arg: resolved at trace time
-        interpret = default_interpret()
     pv = physical_view if lanes == 2 else physical_view_quad
     markers = cache["markers"]
     vp = jnp.asarray(valid_per_page)

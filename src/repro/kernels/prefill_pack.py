@@ -28,7 +28,7 @@ from .ops import layout_window
 @functools.partial(
     jax.jit, static_argnames=("lanes", "page", "use_pack", "interpret"))
 def prefill_pack(pages, idx, marker_lanes, enabled, *, lanes, page,
-                 use_pack=True, interpret=True):
+                 use_pack=True, interpret=None):
     """Pack every touched page group of a freshly scattered prompt at once.
 
     pages:        (B, max_tokens, Hkv, D2) int16 logical page buffer AFTER

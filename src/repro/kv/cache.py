@@ -63,6 +63,7 @@ from ..compression.framing import DEFAULT_MARKER_KEY, DOMAIN_PAIR, DOMAIN_QUAD
 from ..compression.gate import COUNTER_INIT, COUNTER_MAX, ENABLE_THRESHOLD
 from ..compression.predictor import observe_layout
 from ..kernels import ops as kops
+from ..kernels import resolve_interpret
 from ..kernels.ref import MARKER_LANES, marker_to_lanes, slot_markers
 
 
@@ -176,8 +177,7 @@ class CRAMKVCache:
         self.batch = batch
         self.policy = policy
         self.key = key
-        self.interpret = (kops.default_interpret() if interpret is None
-                          else interpret)
+        self.interpret = resolve_interpret(interpret)
         self.tokens = 0
         domain = DOMAIN_PAIR if packing == "pair" else DOMAIN_QUAD
         markers = slot_markers(self.n_groups, key, domain=domain)

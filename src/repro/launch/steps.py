@@ -83,7 +83,7 @@ def make_serve_step(model):
             kw["image_embeds"] = image_embeds
         logits, cache = model.decode_step(params, token, cache, index, **kw)
         next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
-        return next_token, cache
+        return next_token, cache, logits
 
     return serve_step
 

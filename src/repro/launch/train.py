@@ -24,6 +24,7 @@ from .. import configs
 from ..data import DataConfig, make_batch_iterator
 from ..models import ModelConfig, build, count_params, smoke_config
 from ..optim.adamw import adamw_init, make_train_step
+from ..runtime.compile_cache import enable_compile_cache
 from ..runtime.ft import LoopConfig, SimulatedFault, run_with_restarts
 
 PRESETS = {
@@ -65,6 +66,7 @@ def main(argv=None) -> dict:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--json-out", default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = build_config(args)
     seq = args.seq or min(cfg.max_seq, 256)

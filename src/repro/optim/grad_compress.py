@@ -95,7 +95,6 @@ def make_dp_compressed_step(model, mesh, *, lr=1e-3,
     (plain collectives).  A bandwidth `ledger` books each step's wire
     bytes (raw vs what the gate actually sent) under consumer "grad".
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     assert policy in ("dynamic", "static", "off", "auto")
@@ -138,11 +137,11 @@ def make_dp_compressed_step(model, mesh, *, lr=1e-3,
             return new_params, new_err, counter_new, jax.lax.pmean(
                 loss, "data")
 
-        return shard_map(
+        return jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(P(), P(), P(), P("data")),
             out_specs=(P(), P(), P(), P()),
-            check_rep=False,
+            check_vma=False,
         )(params, err, counter, batch)
 
     jit_step = jax.jit(step)

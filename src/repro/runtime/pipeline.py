@@ -19,18 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-def shard_map(f, *, mesh, in_specs, out_specs):
-    """Version-compat shard_map with replication checking off."""
-    try:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    except (AttributeError, TypeError):  # pragma: no cover
-        from jax.experimental.shard_map import shard_map as _sm
-
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
-
-
 def split_stages(stacked_params, n_stages: int):
     """(L, ...) stacked layer params -> (n_stages, L//n_stages, ...)."""
     def re(x):
@@ -78,8 +66,8 @@ def gpipe_apply(stage_params, x_mb, *, mesh: Mesh, stage_fn,
         last = jnp.where(stage == n_stages - 1, 1.0, 0.0).astype(outs.dtype)
         return jax.lax.psum(outs * last, axis)
 
-    return shard_map(
+    return jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(P(axis), P()),
-        out_specs=P(),
+        out_specs=P(), check_vma=False,
     )(stage_params, x_mb)

@@ -111,20 +111,21 @@ def quantum(cache, budget: int) -> int:
 
 
 def drain(cache, slot: int | None = None) -> int:
-    """Settle migration now (evict capture, tests): mark every pending
-    column — of one slot, or all — dirty and repack.  Returns the column
-    count drained."""
+    """Settle the layout now (evict capture, oracles): mark every pending
+    migration column — of one slot, or all — dirty and repack, which also
+    lays out appends still waiting for a repack.  Returns the migration
+    column count drained."""
     pend = pending_mask(cache)
     if slot is not None:
         only = np.zeros_like(pend)
         only[slot] = pend[slot]
         pend = only
     cols = np.flatnonzero(pend.any(0))
-    if cols.size:
-        cache._dirty_b[:, cols] = True
-        # settle under the FROZEN target — drain converges to the current
-        # gate, it is not an observation boundary
-        cache.repack(gate=cache._gate_b)
+    cache._dirty_b[:, cols] = True
+    # settle under the FROZEN target — drain converges to the current
+    # gate, it is not an observation boundary (a clean cache returns
+    # before dispatching anything)
+    cache.repack(gate=cache._gate_b)
     return int(cols.size)
 
 

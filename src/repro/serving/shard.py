@@ -44,7 +44,6 @@ def shard_kv_attend(cache, q, *, shard: "bool | str" = "auto",
     if not want or n_dev <= 1 or b % n_dev:
         return decode(q, kc, valid, interpret=cache.interpret)
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(np.asarray(devs), ("kv",))
@@ -56,11 +55,10 @@ def shard_kv_attend(cache, q, *, shard: "bool | str" = "auto",
              "packed_mask": mask, "markers": markers}
         return decode(qq, c, vv, interpret=interpret)
 
-    fn = shard_map(one_shard, mesh=mesh,
-                   in_specs=(P("kv"), P("kv"), P("kv"), P("kv"), P("kv"),
-                             P("kv")),
-                   out_specs=P("kv"), check_rep=False)  # pallas_call has
-    # no replication rule; every spec is explicit so nothing is inferred
+    fn = jax.shard_map(one_shard, mesh=mesh,
+                       in_specs=(P("kv"),) * 6, out_specs=P("kv"),
+                       check_vma=False)  # pallas_call has no varying-axis
+    # rule; every spec is explicit so nothing is inferred
     return fn(q, kc["slots"], kc["slots_overflow"], kc["strips"],
               kc["packed_mask"], valid)
 

@@ -210,10 +210,10 @@ class SpillStore:
 
     def _capture(self, cache: SlotKVCache, slot: int, seq_id: int) -> dict:
         """Main-thread half of an evict: settle the slot's layout (drain
-        its pending migration under the frozen target, repack), snapshot
-        everything the encode needs as host arrays, and reset the slot."""
+        its pending migration and appends under the frozen target),
+        snapshot everything the encode needs as host arrays, and reset the
+        slot."""
         cache.drain_migration(slot)
-        cache.repack(gate=cache._gate_b)   # settle appends, frozen target
         tokens = int(cache.tokens_b[slot])
         assert tokens > 0, "evicting an empty slot"
         page = cache.page

@@ -115,3 +115,28 @@ def test_fig15_rows_render_from_ledger_view(monkeypatch):
     assert labels["fig15/libq"].startswith("data=0.12")
     assert "wbclean+inv=0.03" in labels["fig15/libq"]
     assert "mispred=0.010" in labels["fig8/libq"]
+
+
+def test_legacy_run_exits_nonzero_when_a_module_raises(monkeypatch):
+    """A module that raises still reports its ERROR row and the suite
+    keeps going, but the run must not exit 0."""
+    import benchmarks.run as br
+    import benchmarks.table3_storage as t3
+
+    def boom():
+        raise RuntimeError("broken module")
+
+    monkeypatch.setattr(t3, "run", boom)
+    monkeypatch.setattr(sys, "argv", ["run.py", "table3_storage"])
+    with pytest.raises(SystemExit) as exc:
+        br.main()
+    assert "table3_storage" in str(exc.value.code)
+
+
+def test_legacy_run_rejects_unknown_module(monkeypatch):
+    import benchmarks.run as br
+
+    monkeypatch.setattr(sys, "argv", ["run.py", "no_such_figure"])
+    with pytest.raises(SystemExit) as exc:
+        br.main()
+    assert exc.value.code == 2

@@ -56,7 +56,7 @@ def test_line_codec_roundtrip_and_size(name):
 @pytest.mark.parametrize("name", ["raw", "bdi", "fpc", "hybrid"])
 def test_line_codec_xp_size_parity(name):
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     codec = codecs.get_codec(name)
     lines = _corpus()

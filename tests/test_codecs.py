@@ -149,7 +149,7 @@ def test_xp_parity_sizes_vs_exact_pack(line):
     """fpc_size_bits / compressed_sizes agree between the numpy and
     jax.numpy backends AND with the exact bit-level packers."""
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     arr = line.reshape(1, LINE)
     words = arr.view("<u4").reshape(1, 16)
@@ -171,7 +171,7 @@ def test_xp_parity_sizes_vs_exact_pack(line):
 @given(adversarial_lines())
 def test_xp_parity_bdi_sizes(line):
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     arr = line.reshape(1, LINE)
     ns, nm = bdi.bdi_sizes(arr)

@@ -25,7 +25,14 @@ def run_py(body: str) -> dict:
         import jax
         import jax.numpy as jnp
         import numpy as np
+        from jax.sharding import AxisType
         assert len(jax.devices()) == 8
+
+        def auto_mesh(shape, names):
+            # the tests drive sharding through `with mesh:` and rules, the
+            # auto-sharding mode; make_mesh defaults to explicit axes
+            return jax.make_mesh(shape, names,
+                                 axis_types=(AxisType.Auto,) * len(shape))
     """) + textwrap.dedent(body)
     proc = subprocess.run(
         [sys.executable, "-c", code],
@@ -45,7 +52,7 @@ def test_pjit_train_step_dp_tp():
         from repro.optim.adamw import adamw_init, make_train_step
         from repro.runtime.sharding import RuleSet, tree_shardings, activation_sharding
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = auto_mesh((4, 2), ("data", "model"))
         cfg = PRESETS["lm2m"]
         model = build(cfg)
         rules = RuleSet()
@@ -75,7 +82,7 @@ def test_sp_decode_kv_sharded_matches_single_device():
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
         from repro.models.attention import chunked_decode_attention
 
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = auto_mesh((8, 1), ("data", "model"))
         B, T, Hq, Hkv, D = 2, 256, 4, 2, 16
         q = jax.random.normal(jax.random.key(0), (B, Hq, D))
         k = jax.random.normal(jax.random.key(1), (B, T, Hkv, D))
@@ -97,7 +104,7 @@ def test_sp_decode_kv_sharded_matches_single_device():
 def test_gpipe_matches_sequential():
     out = run_py("""
         from repro.runtime.pipeline import gpipe_apply, split_stages
-        mesh = jax.make_mesh((4, 2), ("stage", "data"))
+        mesh = auto_mesh((4, 2), ("stage", "data"))
         L, D = 8, 32
         key = jax.random.key(0)
         Ws = jax.random.normal(key, (L, D, D)) * 0.2
@@ -137,7 +144,7 @@ def test_dp_compressed_gradients():
         from repro.models import build
         from repro.optim import grad_compress as gc
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = auto_mesh((8,), ("data",))
         cfg = PRESETS["lm2m"]
         model = build(cfg)
         params, _ = model.init(jax.random.key(0))
@@ -167,7 +174,7 @@ def test_mini_dryrun_lowering():
         from repro.runtime.sharding import RuleSet, activation_sharding
         from repro.launch.hlo_analysis import analyze_compiled
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = auto_mesh((4, 2), ("data", "model"))
         cfg = get_smoke("olmoe_1b_7b")
         spec = ShapeSpec("mini", 128, 8, "train")
         fn, shapes, shards, _ = build_cell(cfg, spec, mesh, RuleSet())

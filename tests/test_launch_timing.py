@@ -48,15 +48,16 @@ def test_launch_decode_no_host_sync_in_timed_region(monkeypatch):
 
     @jax.jit
     def serve_step(params, tok, cache, i):
-        return tok + 1, cache
+        return tok + 1, cache, tok.astype(np.float32)
 
     prompts = np.arange(12, dtype=np.int32).reshape(3, 4)
-    gen, cache, prefill_wall, decode_wall = serve_mod._timed_decode(
+    gen, cache, logits, prefill_wall, decode_wall = serve_mod._timed_decode(
         serve_step, None, prompts, {"k": np.zeros(2)}, gen=5)
 
     # the stub increments the last prompt token once per step
     want = prompts[:, -1:] + 1 + np.arange(5)[None, :]
     np.testing.assert_array_equal(gen, want)
+    np.testing.assert_array_equal(logits, want[:, -1:] - 1)
     assert prefill_wall >= 0.0 and decode_wall >= 0.0
 
     # prefill and decode are SEPARATELY timed regions: four clock reads,
