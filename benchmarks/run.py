@@ -90,12 +90,6 @@ The consolidated JSON report written by --sweep has this schema:
                         migration_bit_identical,
                         prefill_no_slower_than_replay}  # CI-enforced
       },
-      # a serve-spill sweep also APPENDS one compact throughput entry
-      # (git short sha, backend, per-phase + prefill tokens/s, guarantee
-      # flags) to BENCH_history.json at the repo root — the trend line
-      # across PRs, where BENCH_serve.json is only the latest snapshot;
-      # re-running on the same sha REPLACES that sha's entry instead of
-      # appending a duplicate row
       "kernels": {                      # present for --sweep kernels/all
         "modes": {"lanes2"/"lanes4": {"rows": [per block_groups tiling:
                    us_per_call, max_err_vs_oracle, numerics_parity,
@@ -240,51 +234,6 @@ def _sweep_serve_spill(args) -> dict:
     return spill_sweep(steps=args.serve_steps)
 
 
-def _append_bench_history(report: dict) -> None:
-    """Append one compact serve-tier throughput entry to the repo-root
-    BENCH_history.json — BENCH_serve.json is overwritten each run, the
-    history keeps the per-phase tokens/s trend across commits.  Re-runs
-    on the SAME commit replace the previous entry (one row per sha — the
-    trend line tracks commits, not local re-runs); throughput rows are
-    only comparable within one backend, so each entry records it."""
-    sp = report.get("serve_spill")
-    if not sp:
-        return
-    try:
-        import subprocess
-        sha = subprocess.run(["git", "rev-parse", "--short", "HEAD"],
-                             capture_output=True, text=True, cwd=_ROOT,
-                             check=True).stdout.strip()
-    except Exception:
-        sha = "unknown"
-    entry = {
-        "sha": sha,
-        "date": time.strftime("%Y-%m-%d"),
-        "backend": sp["backend"],
-        "tokens_per_s": sp["tokens_per_s"],
-        "prefill": {
-            "tokens_per_s": sp["prefill"]["fused"]["tokens_per_s"],
-            "replay_tokens_per_s": sp["prefill"]["replay"]["tokens_per_s"],
-            "speedup": sp["prefill"]["speedup"],
-        },
-        "migration_phases": {
-            mode: {ph: d["tokens_per_s"] for ph, d in m["phases"].items()}
-            for mode, m in sp["migration"].items()},
-        "guarantee": sp["guarantee"],
-    }
-    path = _ROOT / "BENCH_history.json"
-    try:
-        hist = json.loads(path.read_text()) if path.exists() else []
-    except json.JSONDecodeError:
-        hist = []
-    if hist and sha != "unknown" and hist[-1].get("sha") == sha:
-        print(f"bench history: replacing existing entry for {sha}")
-        hist[-1] = entry
-    else:
-        hist.append(entry)
-    path.write_text(json.dumps(hist, indent=1))
-
-
 def run_sweep(args) -> None:
     # --events/--workloads/--schemes only shape the memsim section; the
     # compress scan always covers the fixed Fig. 4 corpus, so record the
@@ -359,7 +308,6 @@ def run_sweep(args) -> None:
         print("serve-spill guarantee:", flags)
         if not all(flags.values()):
             print("SERVE-SPILL GUARANTEE VIOLATED", file=sys.stderr)
-        _append_bench_history(report)
     if args.sweep in ("kernels", "all"):
         report["kernels"] = _sweep_kernels(args)
         for mode, m in report["kernels"]["modes"].items():

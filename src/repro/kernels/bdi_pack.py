@@ -58,7 +58,7 @@ def _unpack_kernel(packed_ref, base_ref, a_ref, b_ref):
     b_ref[0] = (base[None] + hi).astype(jnp.int16)
 
 
-def _pack_call(kernel, pages, interpret):
+def _pack_call(kernel, name, pages, interpret):
     """Grid the pack kernel over every leading (batch) index of `pages`.
 
     pages: tuple of (..., page, Hkv, D2) int16 arrays of one shape.  The
@@ -74,6 +74,7 @@ def _pack_call(kernel, pages, interpret):
     blk = pl.BlockSpec((1, page, hkv, d2), lambda i: (i, 0, 0, 0))
     packed, base, ok = pl.pallas_call(
         kernel,
+        name=name,
         grid=(n,),
         in_specs=[blk] * len(flat),
         out_specs=[blk, pl.BlockSpec((1, hkv, d2), lambda i: (i, 0, 0)),
@@ -89,7 +90,7 @@ def _pack_call(kernel, pages, interpret):
             ok.reshape(lead) > 0)
 
 
-def _unpack_call(kernel, packed, base, n_pages, interpret):
+def _unpack_call(kernel, name, packed, base, n_pages, interpret):
     """Grid an unpack kernel over the leading axes of `packed`."""
     lead = packed.shape[:-3]
     page, hkv, d2 = packed.shape[-3:]
@@ -97,6 +98,7 @@ def _unpack_call(kernel, packed, base, n_pages, interpret):
     blk = pl.BlockSpec((1, page, hkv, d2), lambda i: (i, 0, 0, 0))
     out = pl.pallas_call(
         kernel,
+        name=name,
         grid=(n,),
         in_specs=[blk, pl.BlockSpec((1, hkv, d2), lambda i: (i, 0, 0))],
         out_specs=[blk] * n_pages,
@@ -111,12 +113,13 @@ def _unpack_call(kernel, packed, base, n_pages, interpret):
 def pack_pair(page_a, page_b, *, interpret: bool | None = None):
     """(..., page, Hkv, D2) int16 x2 -> (packed int16 (..., page, Hkv, D2),
     base int16 (..., Hkv, D2), ok bool (...)); any leading axes batch."""
-    return _pack_call(_pack_kernel, (page_a, page_b), interpret)
+    return _pack_call(_pack_kernel, "pack_pair", (page_a, page_b), interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def unpack_pair(packed, base, *, interpret: bool | None = None):
-    return _unpack_call(_unpack_kernel, packed, base, 2, interpret)
+    return _unpack_call(_unpack_kernel, "unpack_pair", packed, base, 2,
+                        interpret)
 
 
 def _pack_quad_kernel(a_ref, b_ref, c_ref, d_ref, packed_ref, base_ref,
@@ -153,10 +156,11 @@ def pack_quad(page_a, page_b, page_c, page_d, *,
               interpret: bool | None = None):
     """Four (..., page, Hkv, D2) int16 pages -> (packed, base, ok), batched
     over any leading axes like `pack_pair`."""
-    return _pack_call(_pack_quad_kernel, (page_a, page_b, page_c, page_d),
-                      interpret)
+    return _pack_call(_pack_quad_kernel, "pack_quad",
+                      (page_a, page_b, page_c, page_d), interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def unpack_quad(packed, base, *, interpret: bool | None = None):
-    return _unpack_call(_unpack_quad_kernel, packed, base, 4, interpret)
+    return _unpack_call(_unpack_quad_kernel, "unpack_quad", packed, base, 4,
+                        interpret)

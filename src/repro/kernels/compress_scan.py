@@ -304,6 +304,7 @@ def _scan_call(words, *, key, block, interpret):
     words_eo = jnp.concatenate([words[:, 0::2], words[:, 1::2]], axis=1)
     outs = pl.pallas_call(
         functools.partial(_scan_kernel, key=key),
+        name="compress_scan",
         grid=(grid,),
         in_specs=[spec, spec],
         out_specs=(out_spec,) * 4,

@@ -47,6 +47,11 @@ from .ref import MARKER_LANES
 
 NEG_INF = -1e30
 
+# The serve-path kernel's name in the compiled program: a profiler trace
+# shows its op as `cram_decode_attention_batched.<n>` whatever the Python
+# function is called.
+KERNEL_NAME = "cram_decode_attention_batched"
+
 # Default slot-block width (page groups per program) for the batched
 # kernel.  Swept by benchmarks/kernel_bench.blockspec_sweep; the committed
 # BENCH_kernels.json records the measured curve this default came from.
@@ -138,6 +143,7 @@ def cram_decode_attention(q, slots, strips, markers, valid, *,
     assert lanes in (2, 4)
     return pl.pallas_call(
         functools.partial(_kernel, lanes=lanes),
+        name="cram_decode_attention",
         grid=(n,),
         in_specs=[
             pl.BlockSpec((hq, d), lambda i: (0, 0)),
@@ -337,6 +343,7 @@ def cram_decode_attention_batched(q, slots, strips, markers, valid,
     out, bts = pl.pallas_call(
         functools.partial(_batched_kernel, lanes=lanes,
                           slot_bytes=slot_bytes, strip_bytes=strip_bytes),
+        name=KERNEL_NAME,
         grid=(b, nj),
         in_specs=[
             pl.BlockSpec((1, hkv, g, d), lambda bi, j: (bi, 0, 0, 0)),

@@ -39,6 +39,13 @@ had suppressed the tuner's packing pick, `observe_tiers` migrates the
 running cache to that recorded pick (`PolicyChoice.preferred`) via
 `migrate_to` — incrementally, `migrate_budget` page-group columns per
 decode step, never blocking a step (see `serving.migrate`).
+
+Spans: `step`, `attend`, `prefill`, `retire`, `evict` and `wake` each run
+inside a `jax.profiler.TraceAnnotation` named `serve.<method>`, and
+`SlotKVCache.megastep` and `shard_kv_attend` open `serve.megastep*` and
+`serve.attend.*` spans beneath them, so a profiler trace puts the
+device's idle gaps down to the host work over them.  With no profiler
+active a span costs about a microsecond.
 """
 
 from __future__ import annotations
@@ -48,6 +55,7 @@ from dataclasses import dataclass, field
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..bandwidth import AutoTuner, Ledger
 from ..compression.framing import DEFAULT_MARKER_KEY
@@ -201,45 +209,50 @@ class ServeLoop:
         k/v (T, n_kv, d) is compressed page-group-at-a-time in one
         dispatch — or encoded straight to the spill tier for an
         admit-beyond-pool that would itself be the coldest."""
-        return self.admit(seq_id, prompt=(k, v))
+        with TraceAnnotation("serve.prefill", seq=seq_id):
+            return self.admit(seq_id, prompt=(k, v))
 
     def retire(self, seq_id) -> None:
         """Finish a sequence: its lane resets and returns to the free pool
         (or its spill payload is dropped) — the batch axis never grows."""
-        rec = self.seqs.pop(seq_id)
-        if rec.spilled:
-            self.spill.drop(seq_id)
-        else:
-            self.cache.reset_slot(rec.slot)
-            insort(self._free, rec.slot)
-        self.counts["retired"] += 1
+        with TraceAnnotation("serve.retire", seq=seq_id):
+            rec = self.seqs.pop(seq_id)
+            if rec.spilled:
+                self.spill.drop(seq_id)
+            else:
+                self.cache.reset_slot(rec.slot)
+                insort(self._free, rec.slot)
+            self.counts["retired"] += 1
 
     def evict(self, seq_id=None, *,
               protect: frozenset = frozenset()) -> SequenceSlot:
         """Spill one active sequence compressed — `seq_id`, or the coldest
         active one outside `protect`.  The slot frees immediately; with
         async spill the payload re-encode overlaps the next steps."""
-        rec = self.seqs[seq_id] if seq_id is not None else (
-            self._coldest_active(protect))
-        self.spill.evict(self.cache, rec.slot, rec.seq_id)  # resets slot
-        insort(self._free, rec.slot)
-        rec.slot, rec.spilled = -1, True
-        self.counts["evicted"] += 1
-        return rec
+        with TraceAnnotation("serve.evict") as span:
+            rec = self.seqs[seq_id] if seq_id is not None else (
+                self._coldest_active(protect))
+            span.set_metadata(seq=rec.seq_id)
+            self.spill.evict(self.cache, rec.slot, rec.seq_id)  # resets slot
+            insort(self._free, rec.slot)
+            rec.slot, rec.spilled = -1, True
+            self.counts["evicted"] += 1
+            return rec
 
     def wake(self, seq_id, *,
              protect: frozenset = frozenset()) -> SequenceSlot:
         """Restore a spilled sequence into a free slot (evicting the
         coldest active one outside `protect` if needed)."""
-        rec = self.seqs[seq_id]
-        if not rec.spilled:
+        with TraceAnnotation("serve.wake", seq=seq_id):
+            rec = self.seqs[seq_id]
+            if not rec.spilled:
+                return rec
+            slot = self._take_slot(protect)
+            self.spill.restore(self.cache, slot, seq_id)
+            rec.slot, rec.spilled = slot, False
+            rec.last_step = self.clock
+            self.counts["woken"] += 1
             return rec
-        slot = self._take_slot(protect)
-        self.spill.restore(self.cache, slot, seq_id)
-        rec.slot, rec.spilled = slot, False
-        rec.last_step = self.clock
-        self.counts["woken"] += 1
-        return rec
 
     # ------------------------------------------------------------ serving
     def step(self, kv_by_seq: dict) -> dict:
@@ -254,33 +267,35 @@ class ServeLoop:
         + migration quantum + booking as one donated `megastep` dispatch.
         At most `n_slots` sequences fit one step; `step_all` chunks a
         larger batch into waves.  Returns {seq_id: slot}."""
-        self.clock += 1
-        ids = sorted(kv_by_seq)
-        if len(ids) > self.n_slots:
-            raise ValueError(
-                f"step names {len(ids)} sequences but the pool has only "
-                f"{self.n_slots} slots; use step_all() to run in waves")
-        named = frozenset(ids)
-        for sid in ids:
-            if self.seqs[sid].spilled:
-                self.wake(sid, protect=named)
-        slot_ids = []
-        for sid in ids:
-            rec = self.seqs[sid]
-            assert not rec.spilled and rec.slot >= 0, (sid, rec)
-            slot_ids.append(rec.slot)
-        k = jnp.stack([jnp.asarray(kv_by_seq[sid][0]) for sid in ids])
-        v = jnp.stack([jnp.asarray(kv_by_seq[sid][1]) for sid in ids])
-        if self.fused:
-            self.cache.megastep(slot_ids, k, v,
-                                budget=self.migrate_budget)
-        else:
-            self.cache.append_active(slot_ids, k, v)
-            self.cache.migration_quantum(self.migrate_budget)
-            self.cache.account_step()
-        for sid in ids:
-            self.seqs[sid].last_step = self.clock
-        return dict(zip(ids, slot_ids, strict=True))
+        with TraceAnnotation("serve.step", step=self.clock + 1,
+                             n=len(kv_by_seq)):
+            self.clock += 1
+            ids = sorted(kv_by_seq)
+            if len(ids) > self.n_slots:
+                raise ValueError(
+                    f"step names {len(ids)} sequences but the pool has only "
+                    f"{self.n_slots} slots; use step_all() to run in waves")
+            named = frozenset(ids)
+            for sid in ids:
+                if self.seqs[sid].spilled:
+                    self.wake(sid, protect=named)
+            slot_ids = []
+            for sid in ids:
+                rec = self.seqs[sid]
+                assert not rec.spilled and rec.slot >= 0, (sid, rec)
+                slot_ids.append(rec.slot)
+            k = jnp.stack([jnp.asarray(kv_by_seq[sid][0]) for sid in ids])
+            v = jnp.stack([jnp.asarray(kv_by_seq[sid][1]) for sid in ids])
+            if self.fused:
+                self.cache.megastep(slot_ids, k, v,
+                                    budget=self.migrate_budget)
+            else:
+                self.cache.append_active(slot_ids, k, v)
+                self.cache.migration_quantum(self.migrate_budget)
+                self.cache.account_step()
+            for sid in ids:
+                self.seqs[sid].last_step = self.clock
+            return dict(zip(ids, slot_ids, strict=True))
 
     def step_all(self, kv_by_seq: dict) -> dict:
         """`step` for an oversubscribed batch: more named sequences than
@@ -309,15 +324,17 @@ class ServeLoop:
         """Batched decode-attend for `{seq_id: q}` with q (Hq, d); one
         fused (optionally sharded) kernel over the whole slot axis,
         inactive lanes masked by valid.  Returns {seq_id: (Hq, d)}."""
-        ids = sorted(q_by_seq)
-        for sid in ids:
-            assert not self.seqs[sid].spilled, f"seq {sid} is spilled"
-        q0 = np.asarray(q_by_seq[ids[0]])
-        q = np.zeros((self.n_slots,) + q0.shape, np.float32)
-        for sid in ids:
-            q[self.seqs[sid].slot] = np.asarray(q_by_seq[sid])
-        out = shard_kv_attend(self.cache, q, shard=shard)
-        return {sid: out[self.seqs[sid].slot] for sid in ids}
+        with TraceAnnotation("serve.attend", n=len(q_by_seq)):
+            ids = sorted(q_by_seq)
+            for sid in ids:
+                assert not self.seqs[sid].spilled, f"seq {sid} is spilled"
+            with TraceAnnotation("serve.attend.q"):
+                q0 = np.asarray(q_by_seq[ids[0]])
+                q = np.zeros((self.n_slots,) + q0.shape, np.float32)
+                for sid in ids:
+                    q[self.seqs[sid].slot] = np.asarray(q_by_seq[sid])
+            out = shard_kv_attend(self.cache, q, shard=shard)
+            return {sid: out[self.seqs[sid].slot] for sid in ids}
 
     # ------------------------------------------------------------- policy
     def sync_ledger(self) -> None:

@@ -15,6 +15,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..kernels import ops as kops
 
@@ -27,28 +28,33 @@ def shard_kv_attend(cache, q, *, shard: "bool | str" = "auto",
     q: (B, Hq, d) one query row per slot.  Returns (B, Hq, d) float32.
     No bandwidth accounting here — callers charge the step explicitly
     (ServeLoop.attend / account_step)."""
-    cache.repack()
-    q = jnp.asarray(q)
-    if q.ndim == 2:
-        q = q[None]
-    lanes = cache.group_lanes
-    n = cache._active_bucket()
-    kc = cache._kernel_cache(n)
-    valid = jnp.asarray(cache.valid_per_page()[:, : lanes * n])
+    with TraceAnnotation("serve.attend.prep"):
+        cache.repack()
+        q = jnp.asarray(q)
+        if q.ndim == 2:
+            q = q[None]
+        lanes = cache.group_lanes
+        n = cache._active_bucket()
+        kc = cache._kernel_cache(n)
+        valid = jnp.asarray(cache.valid_per_page()[:, : lanes * n])
     decode = (kops.decode_attention_batched if cache.packing == "pair"
               else kops.decode_attention_quad_batched)
     devs = list(devices if devices is not None else jax.devices())
     n_dev = len(devs)
     b = q.shape[0]
     want = shard is True or (shard == "auto" and n_dev > 1)
-    if not want or n_dev <= 1 or b % n_dev:
-        return decode(q, kc, valid, interpret=cache.interpret)
+    with TraceAnnotation("serve.attend.dispatch"):
+        if not want or n_dev <= 1 or b % n_dev:
+            return decode(q, kc, valid, interpret=cache.interpret)
+        return _sharded(decode, devs, q, kc, valid, cache.interpret)
 
+
+def _sharded(decode, devs, q, kc, valid, interpret):
+    """`decode` under shard_map over the slot axis of `devs`."""
     from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(np.asarray(devs), ("kv",))
     markers = kc["markers"]      # replicated (closed over, shared table)
-    interpret = cache.interpret
 
     def one_shard(qq, slots, over, strips, mask, vv):
         c = {"slots": slots, "slots_overflow": over, "strips": strips,

@@ -54,6 +54,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..bandwidth import Ledger
 from ..bandwidth.adapters import kv_read_device, kv_repack_device
@@ -441,59 +442,62 @@ class SlotKVCache(CRAMKVCache):
         layout gate is the frozen `_gate_b`; the §VI counter still
         updates on device every step and is re-sampled at the next
         `refresh_gate`)."""
-        slot_ids = np.asarray(slot_ids, np.int64)
-        assert slot_ids.size > 0, "megastep needs at least one active slot"
-        k = jnp.asarray(k)
-        v = jnp.asarray(v)
-        s, t = k.shape[:2]
-        assert s == slot_ids.size
-        self._check_slot_ids(slot_ids, t)
-        starts = self.tokens_b.copy()
-        active = np.zeros(self.batch, bool)
-        active[slot_ids] = True
-        for sl in slot_ids:
-            self._mark_dirty(int(sl), int(self.tokens_b[sl]), t)
-        self.tokens_b[slot_ids] += t
-        self.tokens = int(self.tokens_b.max())
-        if budget:
-            self.migration_quantum(budget)
-        idx = np.nonzero(self._dirty_b.any(0))[0]
-        w = int(idx.size)
-        wb = min(1 << (w - 1).bit_length(), self.n_groups)
-        idx_pad = np.full(wb, idx[0], np.int32)
-        idx_pad[:w] = idx
-        enabled = self._gate_b
-        span = self.group_lanes * self.page
-        complete = (idx[None, :] + 1) * span <= self.tokens_b[:, None]
-        countable = np.zeros((self.batch, wb), bool)
-        countable[:, :w] = complete & self._uncounted_b[:, idx]
-        n = self._active_bucket()
-        valid = self.valid_per_page()[:, : self.group_lanes * n]
-        self.state, raw_seq, cram_seq = _megastep(
-            self.state, self._marker_lanes, k, v,
-            jnp.asarray(slot_ids, jnp.int32),
-            jnp.asarray(starts, jnp.int32), jnp.asarray(active),
-            jnp.asarray(idx_pad), jnp.asarray(enabled),
-            jnp.asarray(countable), jnp.asarray(valid),
-            lanes=self.group_lanes, slot_bytes=self.slot_bytes,
-            strip_bytes=self.strip_bytes, use_pack=self.policy != "off",
-            dyn=self.policy in ("dynamic", "auto"),
-            interpret=self.interpret)
-        hs = self._host_stats        # same tallies as _book_repack, at the
-        if self.policy == "off":     # padded window actually dispatched
-            hs.pack_skipped_dynamic += self.batch * wb
-        else:
-            hs.pack_attempts += self.batch * wb
-            hs.pack_skipped_dynamic += int((~enabled).sum()) * wb
-        hs.pack_calls += 1
-        hs.pack_pairs_processed += self.batch * wb
-        u = self._uncounted_b[:, idx]
-        u[complete] = False
-        self._uncounted_b[:, idx] = u
-        self._dirty_b[:] = False
-        self._applied_b[:, idx] = enabled[:, None]
-        self._last_enabled = enabled.copy()
-        return {"raw_per_seq": raw_seq, "cram_per_seq": cram_seq}
+        with TraceAnnotation("serve.megastep"):
+            slot_ids = np.asarray(slot_ids, np.int64)
+            assert slot_ids.size > 0, "megastep needs at least one active slot"
+            k = jnp.asarray(k)
+            v = jnp.asarray(v)
+            s, t = k.shape[:2]
+            assert s == slot_ids.size
+            self._check_slot_ids(slot_ids, t)
+            starts = self.tokens_b.copy()
+            active = np.zeros(self.batch, bool)
+            active[slot_ids] = True
+            for sl in slot_ids:
+                self._mark_dirty(int(sl), int(self.tokens_b[sl]), t)
+            self.tokens_b[slot_ids] += t
+            self.tokens = int(self.tokens_b.max())
+            if budget:
+                self.migration_quantum(budget)
+            idx = np.nonzero(self._dirty_b.any(0))[0]
+            w = int(idx.size)
+            wb = min(1 << (w - 1).bit_length(), self.n_groups)
+            idx_pad = np.full(wb, idx[0], np.int32)
+            idx_pad[:w] = idx
+            enabled = self._gate_b
+            span = self.group_lanes * self.page
+            complete = (idx[None, :] + 1) * span <= self.tokens_b[:, None]
+            countable = np.zeros((self.batch, wb), bool)
+            countable[:, :w] = complete & self._uncounted_b[:, idx]
+            n = self._active_bucket()
+            valid = self.valid_per_page()[:, : self.group_lanes * n]
+            with TraceAnnotation("serve.megastep.dispatch"):
+                self.state, raw_seq, cram_seq = _megastep(
+                    self.state, self._marker_lanes, k, v,
+                    jnp.asarray(slot_ids, jnp.int32),
+                    jnp.asarray(starts, jnp.int32), jnp.asarray(active),
+                    jnp.asarray(idx_pad), jnp.asarray(enabled),
+                    jnp.asarray(countable), jnp.asarray(valid),
+                    lanes=self.group_lanes, slot_bytes=self.slot_bytes,
+                    strip_bytes=self.strip_bytes,
+                    use_pack=self.policy != "off",
+                    dyn=self.policy in ("dynamic", "auto"),
+                    interpret=self.interpret)
+            hs = self._host_stats    # same tallies as _book_repack, at the
+            if self.policy == "off":  # padded window actually dispatched
+                hs.pack_skipped_dynamic += self.batch * wb
+            else:
+                hs.pack_attempts += self.batch * wb
+                hs.pack_skipped_dynamic += int((~enabled).sum()) * wb
+            hs.pack_calls += 1
+            hs.pack_pairs_processed += self.batch * wb
+            u = self._uncounted_b[:, idx]
+            u[complete] = False
+            self._uncounted_b[:, idx] = u
+            self._dirty_b[:] = False
+            self._applied_b[:, idx] = enabled[:, None]
+            self._last_enabled = enabled.copy()
+            return {"raw_per_seq": raw_seq, "cram_per_seq": cram_seq}
 
     # ------------------------------------------------------ fused prefill
     def prefill_slot(self, slot: int, k, v, *, budget: int = 0) -> dict:

@@ -109,3 +109,57 @@ def test_compress_scan_compiles(one_chip):
     text = _compiled_text(
         lambda w: _scan_call(w, key=12345, block=BLOCK_LINES, interpret=False), words)
     assert "tpu_custom_call" in text
+
+
+def _named_op(text, name):
+    """The compiled program holds an instruction called `name` or
+    `name.<n>`: the name a profiler trace gives the kernel's op."""
+    import re
+
+    return re.search(rf"%{re.escape(name)}(\.\d+)? = ", text) is not None
+
+
+@pytest.mark.parametrize("lanes", [2, 4])
+def test_decode_kernel_keeps_its_name(one_chip, lanes):
+    """The serve-path kernel's op is named by `KERNEL_NAME`, not by the
+    Python function that wraps it: a renamed wrapper leaves it as is."""
+    from repro.kernels.cram_attention import KERNEL_NAME
+
+    b, n = 2, 4
+    over = ((b, n, PAGE, HKV, D2) if lanes == 2
+            else (b, n, lanes - 1, PAGE, HKV, D2))
+    cache = {
+        "slots": _spec(one_chip, (b, n, PAGE, HKV, D2), jnp.int16),
+        "slots_overflow": _spec(one_chip, over, jnp.int16),
+        "strips": _spec(one_chip, (b, n, HKV, D2 + MARKER_LANES), jnp.int16),
+        "packed_mask": _spec(one_chip, (b, n), jnp.bool_),
+        "markers": _spec(one_chip, (n,), jnp.int32),
+    }
+    q = _spec(one_chip, (b, HQ, HD), jnp.float32)
+    valid = _spec(one_chip, (b, lanes * n), jnp.int32)
+
+    def some_other_name(q, c, v):
+        return kops.decode_attention_fused(q, c, v, lanes=lanes,
+                                           interpret=False)
+
+    text = _compiled_text(some_other_name, q, cache, valid)
+    assert _named_op(text, KERNEL_NAME), KERNEL_NAME
+
+
+@pytest.mark.parametrize("lanes,name", [(2, "pack_pair"), (4, "pack_quad")])
+def test_pack_kernels_keep_their_names(one_chip, lanes, name):
+    win = _spec(one_chip, (2, 4, lanes, PAGE, HKV, D2), jnp.int16)
+    mk = _spec(one_chip, (4, MARKER_LANES), jnp.int16)
+    en = _spec(one_chip, (2,), jnp.bool_)
+    text = _compiled_text(
+        lambda x, m, e: kops.layout_window(x, m, e, use_pack=True,
+                                           interpret=False), win, mk, en)
+    assert _named_op(text, name), name
+
+
+def test_compress_scan_keeps_its_name(one_chip):
+    words = _spec(one_chip, (4096, WORDS_PER_LINE), jnp.int32)
+    text = _compiled_text(
+        lambda w: _scan_call(w, key=12345, block=BLOCK_LINES,
+                             interpret=False), words)
+    assert _named_op(text, "compress_scan")
