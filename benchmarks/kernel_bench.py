@@ -26,8 +26,8 @@ def _timeit(fn, *args, n=5):
     return (time.perf_counter() - t0) / n * 1e6
 
 
-def blockspec_sweep(*, batch=4, n_groups=8, page=8, hkv=1, d=32,
-                    n_timing=5, seed=0) -> dict:
+def blockspec_sweep(*, batch=4, n_groups=8, page=8, hkv=1, d=32, hq=4,
+                    widths=None, n_timing=5, seed=0) -> dict:
     """BlockSpec tuning for the batched fused decode kernel: time every
     block_groups tiling of the slot axis per lanes mode, with parity
     columns (numerics vs the jnp oracle, bytes bit-exact vs the analytic
@@ -37,7 +37,10 @@ def blockspec_sweep(*, batch=4, n_groups=8, page=8, hkv=1, d=32,
 
     On the CPU the kernels are interpreted and the timings are
     structural (relative cost of the tilings and the fused-vs-reference
-    gap); on a TPU they time the compiled kernels.  `backend` names which."""
+    gap); on a TPU they time the compiled kernels.  `backend` names which.
+    `widths` lists the block_groups to time (default: 1, 2, 4, all
+    `n_groups` and the kernel's default, None); the oracle runs jitted,
+    one sequence at a time, so the serving geometry fits one chip."""
     rng = np.random.default_rng(seed)
     d2 = 2 * d
 
@@ -72,14 +75,22 @@ def blockspec_sweep(*, batch=4, n_groups=8, page=8, hkv=1, d=32,
                  for k in ("slots", "slots_overflow", "strips",
                            "packed_mask")}
         cache["markers"] = caches[0]["markers"]
+        del caches
         vp = jnp.asarray(np.stack(valids))
-        q = jnp.asarray(rng.standard_normal((batch, 4, d)), jnp.bfloat16)
+        q = jnp.asarray(rng.standard_normal((batch, hq, d)), jnp.bfloat16)
         ref_fn = (ops.decode_attention_ref_batched if lanes == 2
                   else ops.decode_attention_quad_ref_batched)
-        ref = np.asarray(ref_fn(q, cache, vp))
+        markers = cache["markers"]
+        ref_one = jax.jit(lambda qb, c, v: ref_fn(
+            qb, dict(c, markers=markers), v))
+        ref = np.concatenate([
+            np.asarray(ref_one(q[b:b + 1], {
+                k: v[b:b + 1] for k, v in cache.items() if k != "markers"},
+                vp[b:b + 1]))
+            for b in range(batch)])
         bw = ops.hbm_bytes_moved(cache, vp, lanes=lanes)
         rows, best = [], None
-        for bg in (1, 2, 4, n_groups, None):
+        for bg in widths or (1, 2, 4, n_groups, None):
             out, raw_s, cram_s = ops.decode_attention_fused(
                 q, cache, vp, lanes=lanes, block_groups=bg)
             err = float(np.max(np.abs(np.asarray(out, np.float32) - ref)))

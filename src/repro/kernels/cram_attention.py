@@ -27,10 +27,14 @@ Two kernels:
     it bit-exactly, pinned by tests/test_attention_numerics.py).
 
 The batched kernel compiles for the TPU (tests/test_tpu_compile.py at
-head_dim 128, page 16).  It walks the KV heads one at a time, each head's
-query group (GQA) in one MXU matmul, and selects between the raw and
-packed interpretation of every slot with a jnp.where (a pl.when branch
-is ROADMAP S4).
+head_dim 128, page 16).  Each program builds its block's token tile by
+page group, not by slot: a packed group gives the `lanes` pages
+delta-decoded from its lead slot and strip, a raw group lane 0 of each
+of its slots, chosen by a jnp.where on the lead slot's marker verdict (a
+pl.when branch is ROADMAP S4), so a packed group's dead overflow slots
+are never taken as tokens.  It then attends to the whole tile once per
+KV head: one QK^T matmul for the head's query group (GQA), one masked
+online-softmax update, one PV matmul.
 """
 
 from __future__ import annotations
@@ -53,9 +57,11 @@ NEG_INF = -1e30
 KERNEL_NAME = "cram_decode_attention_batched"
 
 # Default slot-block width (page groups per program) for the batched
-# kernel.  Swept by benchmarks/kernel_bench.blockspec_sweep; the committed
-# BENCH_kernels.json records the measured curve this default came from.
-DEFAULT_BLOCK_GROUPS = 4
+# kernel.  On a TPU v5e at page 16, 8 KV heads, head_dim 128 and 1024
+# groups the kernel takes 10.0 / 9.2 / 9.2 ms (pair) and 17.7 / 17.7 /
+# 16.8 ms (quad) for a batch of 8 at 4 / 8 / 16 groups a block; a wider
+# block compiles longer.  PERF.md keeps the curve.
+DEFAULT_BLOCK_GROUPS = 8
 
 
 def _kernel(q_ref, slot_ref, strip_ref, marker_ref, valid_ref,
@@ -184,16 +190,12 @@ def _batched_kernel(q_ref, slot_ref, strip_ref, marker_ref, valid_ref,
     @pl.when(j == 0)
     def _init():
         m_s[...] = jnp.full_like(m_s[...], NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s[...])
-        acc_s[...] = jnp.zeros_like(acc_s[...])
         byt_s[...] = jnp.zeros_like(byt_s[...])
 
     _, kk, page, hkv, d2 = slot_ref.shape
     d = d2 // 2
-    tl = lanes * page                     # tokens one slot can hold
     scale = 1.0 / (d ** 0.5)
     hi = jax.lax.Precision.HIGHEST
-    tok = jax.lax.broadcasted_iota(jnp.int32, (1, tl), 1)
 
     def marker_ok(tail, expected):
         """(.., Hkv, 2) strip tails vs (.., 1, 2) marker lanes -> packed?
@@ -203,53 +205,72 @@ def _batched_kernel(q_ref, slot_ref, strip_ref, marker_ref, valid_ref,
         return jnp.min(jnp.min(eq, axis=-1, keepdims=True), axis=-2,
                        keepdims=True) > 0
 
-    # One slot at a time, in order: the online softmax then sums over the
-    # same token count whatever the block width or the attend bucket, so a
-    # sequence attends bit-identically alone or among longer neighbours
-    # (fully masked slots are exact no-ops).
-    def slot_step(i, carry):
-        packed = marker_ok(strip_ref[0, i, :, d2:], marker_ref[0, i])
-        mask = jnp.zeros((1, tl), jnp.bool_)
-        for ln in range(lanes):
-            mask = mask | ((tok >= ln * page)
-                           & (tok < ln * page + valid_ref[0, i, :, ln:ln + 1]))
-        # per KV head: decode both interpretations of the slot, select by
-        # marker, online softmax for that head's query group.  A bf16 bit
-        # pattern b widens exactly to the f32 bit pattern b << 16, so raw
-        # and delta-decoded values go straight to f32 (the int16 wrap of
-        # base + delta falls off the top of the shift).
-        for h in range(hkv):
-            v_u = slot_ref[0, i, :, h, :].astype(jnp.int32) & 0xFFFF
-            base = strip_ref[0, i, pl.ds(h, 1), :d2].astype(jnp.int32)
-            if lanes == 2:                          # int8-delta pair codec
-                deltas = [((v_u & 0xFF) ^ 0x80) - 0x80,
-                          (((v_u >> 8) & 0xFF) ^ 0x80) - 0x80]
-            else:                                   # int4-delta quad codec
-                deltas = [(((v_u >> sh) & 0xF) ^ 0x8) - 0x8
-                          for sh in (0, 4, 8, 12)]
-            pages = [jnp.where(packed, (base + dl) << 16,
-                               (v_u << 16) if ln == 0 else 0)
-                     for ln, dl in enumerate(deltas)]
-            kvf = jax.lax.bitcast_convert_type(
-                jnp.concatenate(pages, axis=0), jnp.float32)  # (tl, D2)
-            q = q_ref[0, h].astype(jnp.float32)               # (G, D)
-            s = jax.lax.dot_general(
-                q, kvf[:, :d], (((1,), (1,)), ((), ())), precision=hi,
-                preferred_element_type=jnp.float32) * scale
-            s = jnp.where(mask, s, NEG_INF)                   # (G, tl)
-            m_prev = m_s[h]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-            l_s[h] = l_s[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            acc_s[h] = acc_s[h] * alpha + jnp.dot(
-                p, kvf[:, d:], precision=hi,
-                preferred_element_type=jnp.float32)
-            m_s[h] = m_new
-        return carry
-
-    jax.lax.fori_loop(0, kk, slot_step, 0)
     is_packed = marker_ok(strip_ref[0, :, :, d2:], marker_ref[0])  # (K,1,1)
+
+    # The block's token tile, by page group: a packed group contributes
+    # the `lanes` pages delta-decoded from its lead slot and strip, a raw
+    # group lane 0 of each of its `lanes` slots.  Group packedness is the
+    # lead slot's marker verdict, so a packed group's overflow slots are
+    # never taken as tokens; each page's valid count comes from the slot
+    # its tokens come from.  Row r of the tile is token r % page of page
+    # r // page.
+    bg = kk // lanes
+    tb = kk * page                        # tokens the block's tile holds
+    tok = jax.lax.broadcasted_iota(jnp.int32, (1, tb), 1)
+    mask = jnp.zeros((1, tb), jnp.bool_)
+    for gi in range(bg):
+        lead = gi * lanes
+        for ln in range(lanes):
+            n_valid = jnp.where(is_packed[lead],
+                                valid_ref[0, lead, :, ln:ln + 1],
+                                valid_ref[0, lead + ln, :, 0:1])  # (1, 1)
+            off = (lead + ln) * page
+            mask = mask | ((tok >= off) & (tok < off + n_valid))
+
+    # One attend per KV head over the whole tile: one QK^T matmul, one
+    # masked online-softmax update, one PV matmul.  The block partition
+    # depends only on the block width, and a fully masked block is an
+    # exact no-op (alpha 1, p 0), so a sequence attends bit-identically
+    # alone or among longer neighbours.  A bf16 bit pattern b widens
+    # exactly to the f32 bit pattern b << 16, so raw and delta-decoded
+    # values go straight to f32 (the int16 wrap of base + delta falls off
+    # the top of the shift).  A sequence's first block sets `l` and `acc`
+    # where later blocks rescale and add, so no zero start can be folded
+    # into the PV matmul: the CPU interpreter would otherwise fuse the
+    # matmul of a one- or two-program grid differently and change its
+    # last bits.
+    grp_packed = is_packed.reshape(bg, lanes, 1, 1)[:, 0:1]   # (bg,1,1,1)
+    for h in range(hkv):
+        x = slot_ref[0, :, :, h, :].astype(jnp.int32).reshape(
+            bg, lanes, page, d2)
+        lead_u = x[:, 0] & 0xFFFF                              # (bg,page,D2)
+        base = strip_ref[0, :, pl.ds(h, 1), :d2].astype(jnp.int32).reshape(
+            bg, lanes, 1, d2)[:, 0]                            # (bg, 1, D2)
+        if lanes == 2:                              # int8-delta pair codec
+            deltas = [((lead_u & 0xFF) ^ 0x80) - 0x80,
+                      (((lead_u >> 8) & 0xFF) ^ 0x80) - 0x80]
+        else:                                       # int4-delta quad codec
+            deltas = [(((lead_u >> sh) & 0xF) ^ 0x8) - 0x8
+                      for sh in (0, 4, 8, 12)]
+        packed = jnp.stack([base + dl for dl in deltas], axis=1)
+        kvf = jax.lax.bitcast_convert_type(
+            (jnp.where(grp_packed, packed, x) << 16).reshape(tb, d2),
+            jnp.float32)                                       # (tb, D2)
+        q = q_ref[0, h].astype(jnp.float32)                   # (G, D)
+        s = jax.lax.dot_general(
+            q, kvf[:, :d], (((1,), (1,)), ((), ())), precision=hi,
+            preferred_element_type=jnp.float32) * scale
+        s = jnp.where(mask, s, NEG_INF)                       # (G, tb)
+        m_prev = m_s[h]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        p_sum = jnp.sum(p, axis=-1, keepdims=True)
+        pv = jnp.dot(p, kvf[:, d:], precision=hi,
+                     preferred_element_type=jnp.float32)
+        l_s[h] = jnp.where(j == 0, p_sum, l_s[h] * alpha + p_sum)
+        acc_s[h] = jnp.where(j == 0, pv, acc_s[h] * alpha + pv)
+        m_s[h] = m_new
 
     # --- bytes-moved for exactly the layout this block walked.  Flat-slot
     # form of the `ops.hbm_bytes_moved` group model: the lead slot of a
@@ -260,7 +281,7 @@ def _batched_kernel(q_ref, slot_ref, strip_ref, marker_ref, valid_ref,
     # slot's marker verdict, group liveness the union over its slots.
     raw_b = jnp.zeros((1, 1), jnp.int32)
     cram_b = jnp.zeros((1, 1), jnp.int32)
-    for gi in range(kk // lanes):
+    for gi in range(bg):
         grp_live = jnp.zeros((1, 1), jnp.bool_)
         for i in range(gi * lanes, (gi + 1) * lanes):
             n_live = sum((valid_ref[0, i, :, ln:ln + 1] > 0).astype(jnp.int32)
@@ -309,10 +330,11 @@ def cram_decode_attention_batched(q, slots, strips, markers, valid,
 
     Each program DMAs `block_groups * lanes` consecutive slots + strips
     of one sequence (`block_groups` is the tunable BlockSpec axis, swept
-    by benchmarks/kernel_bench.py).  Returns (out (B, Hq, D) float32,
-    bytes (B, 2) int32) where bytes[b] = (raw, cram) bytes one decode
-    step DMAs for sequence b under the layout the kernel walked —
-    bit-identical to `ops.hbm_bytes_moved` per-sequence totals.
+    by benchmarks/kernel_bench.py); fewer groups than one block are
+    padded to one with groups that hold no valid token.  Returns (out
+    (B, Hq, D) float32, bytes (B, 2) int32) where bytes[b] = (raw, cram)
+    bytes one decode step DMAs for sequence b under the layout the kernel
+    walked — bit-identical to `ops.hbm_bytes_moved` per-sequence totals.
     """
     assert lanes in (2, 4)
     b, hq, d = q.shape
@@ -321,6 +343,19 @@ def cram_decode_attention_batched(q, slots, strips, markers, valid,
         valid, predictor = valid[None], predictor[None]
     bc, n, page, hkv, d2 = slots.shape
     n_groups = n // lanes
+    wanted = DEFAULT_BLOCK_GROUPS if block_groups is None else block_groups
+    if n_groups < wanted:
+        # pad a bucket narrower than one block to one block, with groups
+        # that hold no valid token: its tokens then meet the same block,
+        # and the same matmul shapes, as in a wider bucket's first block
+        pad = (wanted - n_groups) * lanes
+        widen = lambda x, ax, by=pad: jnp.pad(
+            x, [(0, by if i == ax else 0) for i in range(jnp.ndim(x))])
+        slots, strips, valid = widen(slots, 1), widen(strips, 1), widen(
+            valid, 1)
+        markers = widen(markers, 0)
+        predictor = widen(predictor, 1, wanted - n_groups)
+        n, n_groups = n + pad, wanted
     bg = resolve_block_groups(n_groups, block_groups)
     kk = bg * lanes
     nj = n // kk

@@ -147,15 +147,19 @@ def _cram_pages(rng, lanes, n_groups, comp):
     return pages
 
 
-def _batched_cram_cache(rng, lanes, n_groups, batch):
-    """Per-sequence caches (stacked leaves, shared markers) with mixed
-    packed/raw groups and per-sequence partial-page valid counts."""
+def _batched_cram_cache(rng, lanes, n_groups, batch, packed="mixed"):
+    """Per-sequence caches (stacked leaves, shared markers) with
+    per-sequence partial-page valid counts; `packed` says which groups
+    delta-compress: a random half ("mixed"), every group ("all") or none
+    ("none")."""
     build = (kops.build_cram_cache if lanes == 2
              else kops.build_cram_cache_quad)
     caches, valids = [], []
     n_pages = lanes * n_groups
     for b in range(batch):
-        comp = rng.random(n_groups) < 0.5
+        comp = {"mixed": rng.random(n_groups) < 0.5,
+                "all": np.ones(n_groups, bool),
+                "none": np.zeros(n_groups, bool)}[packed]
         caches.append(build(jnp.asarray(_cram_pages(rng, lanes, n_groups,
                                                     comp)), interpret=True))
         # odd token counts: partial last page + dead tail groups
@@ -165,9 +169,10 @@ def _batched_cram_cache(rng, lanes, n_groups, batch):
     cache = {k: jnp.stack([c[k] for c in caches])
              for k in ("slots", "slots_overflow", "strips", "packed_mask")}
     cache["markers"] = caches[0]["markers"]
-    # mixed layouts must actually be exercised
+    # the layouts asked for must actually be exercised
     ok = np.asarray(cache["packed_mask"])
-    assert ok.any() and not ok.all(), "want mixed packed/raw groups"
+    assert {"mixed": ok.any() and not ok.all(), "all": ok.all(),
+            "none": not ok.any()}[packed], f"want {packed} packed groups"
     return cache, jnp.asarray(np.stack(valids))
 
 
@@ -188,11 +193,19 @@ def _legacy_vmap_decode(q, cache, vp, lanes):
                          cache["strips"], cache["packed_mask"], vp)
 
 
-@pytest.mark.parametrize("lanes,batch", [(2, 3), (4, 5)])
-def test_fused_batched_kernel_matches_oracle_and_legacy(lanes, batch):
+@pytest.mark.parametrize("lanes,batch,packed", [
+    pytest.param(2, 3, "mixed", id="2-3"),
+    pytest.param(4, 5, "mixed", id="4-5"),
+    pytest.param(2, 3, "all", id="2-3-all-packed"),
+    pytest.param(4, 5, "all", id="4-5-all-packed"),
+    pytest.param(2, 3, "none", id="2-3-none-packed"),
+    pytest.param(4, 5, "none", id="4-5-none-packed"),
+])
+def test_fused_batched_kernel_matches_oracle_and_legacy(lanes, batch,
+                                                        packed):
     rng = np.random.default_rng(42 + lanes)
     n_groups = 4
-    cache, vp = _batched_cram_cache(rng, lanes, n_groups, batch)
+    cache, vp = _batched_cram_cache(rng, lanes, n_groups, batch, packed)
     q = jnp.asarray(rng.normal(size=(batch, 4, HD)).astype(np.float32),
                     jnp.bfloat16)
     ref_fn = (kops.decode_attention_ref_batched if lanes == 2
@@ -205,8 +218,9 @@ def test_fused_batched_kernel_matches_oracle_and_legacy(lanes, batch):
                                                 interpret=True)
         np.testing.assert_allclose(np.asarray(out), ref,
                                    atol=1e-4, rtol=1e-4)
-        # vs the old per-sequence vmap path: same kernel math, same
-        # accumulation order within a slot — tight tolerance
+        # vs the old per-sequence vmap path: same kernel math, the online
+        # softmax summed block by block instead of slot by slot — tight
+        # tolerance
         np.testing.assert_allclose(np.asarray(out), legacy,
                                    atol=1e-5, rtol=1e-5)
 
@@ -250,3 +264,60 @@ def test_fused_kernel_shared_cache_path():
     bw = kops.hbm_bytes_moved(cache, vp, lanes=2)
     assert np.asarray(raw_s).tolist() == [bw["raw_bytes"]] * 5
     assert np.asarray(cram_s).tolist() == [bw["cram_bytes"]] * 5
+
+
+@pytest.mark.parametrize("lanes", [2, 4])
+def test_attend_is_block_invariant(lanes):
+    """At a fixed block width a sequence attends bit-identically alone in
+    a bucket of `bg` groups, beside a longer neighbour in a bucket of
+    4·bg groups, and alone with trailing fully masked blocks: the block
+    partition does not depend on the bucket and a fully masked block is
+    an exact no-op.  A sequence shorter than one block also attends
+    alike in a bucket narrower than one block, which is padded to one."""
+    rng = np.random.default_rng(11 + lanes)
+    bg = 2
+    cache, _ = _batched_cram_cache(rng, lanes, 4 * bg, 2)
+    q = jnp.asarray(rng.normal(size=(2, 4, HD)).astype(np.float32),
+                    jnp.bfloat16)
+
+    def attend(vp, n_seq, n_groups):
+        c = {k: x[:n_groups] if k == "markers" else x[:n_seq, :n_groups]
+             for k, x in cache.items()}
+        out, _, _ = kops.decode_attention_fused(
+            q[:n_seq], c, jnp.asarray(vp[:n_seq, :lanes * n_groups]),
+            lanes=lanes, block_groups=bg, interpret=True)
+        return np.asarray(out)[0]
+
+    for n_live, buckets in ((bg, (bg, 4 * bg)), (1, (1, bg, 4 * bg))):
+        vp = np.zeros((2, lanes * 4 * bg), np.int32)
+        vp[0, :lanes * n_live] = PAGE
+        vp[0, lanes * n_live - 1] = PAGE // 2 + 1      # partial last page
+        vp[1] = PAGE                                    # longer neighbour
+        alone = attend(vp, 1, buckets[0])
+        assert np.isfinite(alone).all()
+        for n_groups in buckets[1:]:
+            np.testing.assert_array_equal(attend(vp, 1, n_groups), alone)
+        np.testing.assert_array_equal(attend(vp, 2, 4 * bg), alone)
+
+
+@pytest.mark.parametrize("lanes", [2, 4])
+def test_dead_overflow_slots_are_never_read(lanes):
+    """Every group packed and every overflow slot holding random bits
+    (NaN and Inf patterns among them): the kernel answers as the oracle
+    does over zeroed overflow slots, so a packed group's dead overflow
+    slots are never read as tokens."""
+    rng = np.random.default_rng(5 + lanes)
+    cache, vp = _batched_cram_cache(rng, lanes, 4, 2, "all")
+    q = jnp.asarray(rng.normal(size=(2, 4, HD)).astype(np.float32),
+                    jnp.bfloat16)
+    ref_fn = (kops.decode_attention_ref_batched if lanes == 2
+              else kops.decode_attention_quad_ref_batched)
+    ref = np.asarray(ref_fn(q, cache, vp))
+    over = cache["slots_overflow"]
+    garbage = dict(cache, slots_overflow=jnp.asarray(
+        rng.integers(-2**15, 2**15, size=over.shape), jnp.int16))
+    for bg in (1, 2):
+        out, _, _ = kops.decode_attention_fused(
+            q, garbage, vp, lanes=lanes, block_groups=bg, interpret=True)
+        np.testing.assert_allclose(np.asarray(out), ref,
+                                   atol=1e-4, rtol=1e-4)
