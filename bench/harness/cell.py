@@ -3,6 +3,7 @@ and the result line."""
 
 from __future__ import annotations
 
+import functools
 import gc
 import math
 import shutil
@@ -12,9 +13,8 @@ import time
 from dataclasses import dataclass
 from pathlib import Path
 
-from . import check, device, spec, trace, work
+from . import check, device, spec, trace
 from .runner import Runner, Sampler
-from .kvgen import Generator
 from .stats import percentile
 from .traffic import make_plan
 from .warmup import Warmer
@@ -47,7 +47,7 @@ class Run:
     """What the metric readers read."""
 
     cell: str
-    geo: work.Geometry
+    geo: object                    # the kind's work object (harness.work)
     peaks: dict
     rec: object
     setup_s: float
@@ -127,26 +127,19 @@ def run(cell_name: str, *, seed: int, seconds: float, trace_on: bool,
     sys.path.insert(0, str(spec.ROOT / "src"))
     import jax
     from repro.runtime.compile_cache import enable_compile_cache
-    from repro.serving import ServeLoop
 
     cache_dir = enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     counter = counter or CompileCounter()
 
-    cfg, mix = cell.config, cell.mix
-    page = int(cfg["assumed"]["page"])
-    n_kv, hq = int(cfg["num_key_value_heads"]), int(cfg["num_attention_heads"])
-    d = int(cfg["assumed"]["head_dim"])
+    cfg, mix, kind = cell.config, cell.mix, cell.kind()
     plan = make_plan(mix, seed)
-    # the serve tier at its defaults, or as the mix's "serve" entry sets
-    # it (e.g. {"packing": "quad"})
-    loop = ServeLoop(slots=plan.slots, max_pages=plan.capacity // page,
-                     page=page, n_kv=n_kv, head_dim=d, **mix.get("serve", {}))
+    loop = kind.make_loop(cfg, plan, mix.get("serve", {}))
     if require_tpu:
         assert loop.cache.interpret is False, "Pallas kernels interpreted"
-    geo = work.Geometry(page, loop.cache.group_lanes, n_kv, d, hq)
-    gen = Generator(seed, n_kv=n_kv, d=d, hq=hq, kv=mix["kv"])
+    geo = kind.geometry(cfg, loop)
+    gen = kind.Generator(seed, cfg, mix)
     smp = cell.checks["sample"]
     runner = Runner(loop, gen, plan, sampler=Sampler(
         seed, smp["answers"], smp.get("woken", 0)))
@@ -201,9 +194,10 @@ def run(cell_name: str, *, seed: int, seconds: float, trace_on: bool,
     runner.loop = warmer.loop = loop = None
     gc.collect()
     t = time.time()
-    readings = check.compare(items, gen, cell.reference(),
-                             control=cfg["assumed"]["kv_dtype"]
-                             if control else None)
+    readings = check.compare(
+        items, gen, functools.partial(kind.reference_answers,
+                                      cell.reference()),
+        control=cfg["assumed"]["kv_dtype"] if control else None)
     readings["tokens_mismatch"] = tokens_mismatch
     _log(f"reference: {time.time() - t:.3f} s for "
          f"{readings['answers_checked']} answers")

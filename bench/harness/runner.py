@@ -1,8 +1,9 @@
 """Set-up and the timed window of one cell, over `repro.serving.ServeLoop`.
 
-Each decoded token is an append of its KV through `ServeLoop.step`, an
-`attend` with the session's query heads, and a `block_until_ready` on
-the attend output, as a sampler would wait for it.  Where more sessions
+Each decoded token is an append of its cache payload (the attention
+kind's feed: K and V for GQA) through `ServeLoop.step`, an `attend` with
+the session's query, and a `block_until_ready` on the attend output, as
+a sampler would wait for it.  Where more sessions
 decode than there are slots, they run one wave of at most `slots`
 sessions at a time, in `step_all`'s order (resident sessions first):
 the program has no combined append-and-attend entry over an
@@ -118,9 +119,9 @@ class Runner:
     # ------------------------------------------------------------ set-up
     def prefill(self, s: Session, length: int) -> None:
         with TraceAnnotation("bench.gen"):
-            k, v = self.gen.kv_each([s.uid], [0], length)
+            feed = self.gen.feed_each([s.uid], [0], length)
         with TraceAnnotation("bench.prefill"):
-            self.loop.prefill(s.uid, k[0], v[0])
+            self.loop.prefill(s.uid, *feed[0])
         s.tokens = length
 
     def admit_all(self) -> None:
@@ -158,12 +159,12 @@ class Runner:
         uids = self._pad([s.uid for s in wave])
         starts = self._pad([s.tokens for s in wave])
         with TraceAnnotation("bench.gen"):
-            k, v = self.gen.kv_each(uids, starts, 1)
+            feed = self.gen.feed_each(uids, starts, 1)
             q = self.gen.q_each(uids, starts)
         woken = {s.uid for s in wave if self.loop.seqs[s.uid].spilled}
-        kv_by_seq = {s.uid: (k[i], v[i]) for i, s in enumerate(wave)}
+        feed_by_seq = {s.uid: feed[i] for i, s in enumerate(wave)}
         with TraceAnnotation("bench.step"):
-            self.loop.step(kv_by_seq)
+            self.loop.step(feed_by_seq)
         for s in wave:
             s.tokens += 1
         t = self._attend(wave, q, woken)
@@ -229,13 +230,13 @@ class Runner:
             u, a = row.user[0], row.answer[0]
         s.due = None
         with TraceAnnotation("bench.gen"):
-            k, v = self.gen.kv_each([s.uid], [s.tokens], u)
+            feed = self.gen.feed_each([s.uid], [s.tokens], u)
             q = self.gen.q_each(self._pad([s.uid]),
                                 self._pad([s.tokens + u - 1]))
         woken = {s.uid} if self.loop.seqs[s.uid].spilled else set()
-        kv_by_seq = {s.uid: (k[0], v[0])}
+        feed_by_seq = {s.uid: feed[0]}
         with TraceAnnotation("bench.step"):
-            self.loop.step(kv_by_seq)
+            self.loop.step(feed_by_seq)
         s.tokens += u
         t = self._attend([s], q, woken)
         if self.recording:

@@ -69,7 +69,23 @@ def chat_benchmark() -> Path:
     return path
 
 
-def tiny(cell_name: str) -> dict:
+# A cell whose configuration names an attention kind the harness does not
+# know (`fixtures/seam/`: one latent row per token), at tiny size in its
+# own BENCHMARK.json.
+SEAM_CELL = "latent_test.endless"
+SEAM_BENCHMARK = BENCH / "tests" / "fixtures" / "seam" / "BENCHMARK.json"
+
+
+def benchmark_of(cell_name: str) -> Path | None:
+    """The BENCHMARK.json a test cell is read from (None: the repo's)."""
+    if cell_name == CHAT_CELL["name"]:
+        return chat_benchmark()
+    return SEAM_BENCHMARK if cell_name == SEAM_CELL else None
+
+
+def tiny(cell_name: str) -> dict | None:
+    if cell_name == SEAM_CELL:
+        return None
     kind = "chat" if cell_name.startswith("olmoe") else "endless"
     return {"config": TINY_CONFIG, "mix": TINY_MIX[kind]}
 
@@ -80,9 +96,8 @@ def run_tiny(cell_name: str, *, seed: int = 2**31 + 12345,
     """One run of a cell at tiny size on the CPU (no chip check)."""
     from harness import cell
 
-    chat = cell_name == CHAT_CELL["name"]
     return cell.run(cell_name, seed=seed, seconds=seconds,
                     trace_on=trace_on, require_tpu=False,
                     peaks_kind="TPU v5 lite", control=control,
                     overrides=tiny(cell_name),
-                    benchmark=chat_benchmark() if chat else None)
+                    benchmark=benchmark_of(cell_name))

@@ -1,6 +1,8 @@
 """Every cell runs end to end at tiny size on the CPU (Pallas interpret
 mode): the control flow, the comparison with the reference, and the keys
-of the result line.  Also: the command refuses to run without a TPU."""
+of the result line; so does a cell of an attention kind the harness does
+not know (`conftest.SEAM_CELL`).  Also: the command refuses to run
+without a TPU."""
 
 import json
 import os
@@ -8,21 +10,20 @@ import subprocess
 import sys
 
 import pytest
-from conftest import CHAT_CELL, chat_benchmark, run_tiny
+from conftest import CHAT_CELL, SEAM_CELL, benchmark_of, run_tiny
 
 from harness import spec
 
 BENCH_CELLS = [w["name"] for w in json.loads(
     (spec.ROOT / "BENCHMARK.json").read_text())["workloads"]]
-CELLS = BENCH_CELLS + [CHAT_CELL["name"]]
+CELLS = BENCH_CELLS + [CHAT_CELL["name"], SEAM_CELL]
 
 
 def _keys_ok(res, cell, trace_on):
     assert list(res)[:5] == ["correct", "attempted", "failed", "metrics",
                              "device"]
     assert list(res)[-1] == "checks"
-    c = spec.load(cell, chat_benchmark() if cell == CHAT_CELL["name"]
-                  else None)
+    c = spec.load(cell, benchmark_of(cell))
     want = {m["name"] for m in (c.per_layer if trace_on else c.end_to_end)}
     # on the CPU the allocator keeps no peak; device-trace metrics need a
     # device plane: both are silent there

@@ -1,18 +1,19 @@
 """The control: the plain reference put in the program's place with K
 and V rounded through float8 (the precision below the configuration's
 bfloat16), read on the same answers.  It has to come out as not correct
-under every cell's limit, while the program passes."""
+under every cell's limit, while the program passes; the control of an
+attention kind the harness does not know goes through the same call."""
 
 import json
 
 import pytest
-from conftest import CHAT_CELL, run_tiny
+from conftest import CHAT_CELL, SEAM_CELL, run_tiny
 
 from harness import spec
 
 CELLS = [w["name"] for w in json.loads(
     (spec.ROOT / "BENCHMARK.json").read_text())["workloads"]] + [
-        CHAT_CELL["name"]]
+        CHAT_CELL["name"], SEAM_CELL]
 
 
 @pytest.mark.parametrize("cell", CELLS)

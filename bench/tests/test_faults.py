@@ -1,12 +1,14 @@
 """The timed path broken underneath: each fault the cells can have is
 planted in the program, a whole run is driven (no chip check), and
-`correct` has to come out false.  One chip has no exchange between
-chips to leave out."""
+`correct` has to come out false, in a GQA cell and in the cell of an
+attention kind the harness does not know (whose loop holds one latent
+row as K and V, so the faults planted in ServeLoop reach it too).  One
+chip has no exchange between chips to leave out."""
 
 import jax
 import jax.numpy as jnp
 import pytest
-from conftest import run_tiny
+from conftest import SEAM_CELL, run_tiny
 
 from repro.serving import loop as loop_mod
 from repro.serving import slots as slots_mod
@@ -69,10 +71,11 @@ FAULTS = {"state_unchanged": _state_unchanged, "half_batch": _half_batch,
           "token_altered": _token_altered, "answer_altered": _answer_altered}
 
 
+@pytest.mark.parametrize("cell", [P4, SEAM_CELL])
 @pytest.mark.parametrize("fault", sorted(FAULTS))
-def test_fault_is_not_correct(fault, monkeypatch):
+def test_fault_is_not_correct(fault, cell, monkeypatch):
     FAULTS[fault](monkeypatch)
-    res = run_tiny(P4, seconds=1.5, seed=2**31 + 7)
+    res = run_tiny(cell, seconds=1.5, seed=2**31 + 7)
     assert not res["correct"], (fault, res["checks"])
 
 

@@ -1,15 +1,20 @@
-"""The generator gives every (seed, session, position) the same KV and
-query, whatever block it was made in: the reference regenerates what the
-window fed in other block shapes than the feed used."""
+"""The GQA kind's generator gives every (seed, session, position) the
+same KV and query, whatever block it was made in: the reference
+regenerates what the window fed in other block shapes than the feed
+used."""
 
 import numpy as np
+from conftest import TINY_CONFIG
 
-from harness.kvgen import Generator, root_key
+from harness import spec
+from harness.kvgen import root_key
+
+GQA = spec.kind("gqa")
 
 
 def _gen(dist):
-    return Generator(2**31 + 987654321, n_kv=2, d=32, hq=4,
-                     kv={"dist": dist, "scale": 0.002})
+    return GQA.Generator(2**31 + 987654321, TINY_CONFIG,
+                         {"kv": {"dist": dist, "scale": 0.002}})
 
 
 def test_blocks_agree_bit_for_bit():
@@ -19,12 +24,12 @@ def test_blocks_agree_bit_for_bit():
         uids, starts = [3, 5, 7, 3], [100, 517, 1023, 101]
         k1, v1 = g.kv(uids, starts, 1)
         for i, (u, s) in enumerate(zip(uids, starts, strict=True)):
-            kc, vc = g.session_kv(u, 1536)
+            kc, vc = g.session_state(u, 1536)
             assert np.array_equal(np.asarray(k1[i, 0]), np.asarray(kc[s]))
             assert np.array_equal(np.asarray(v1[i, 0]), np.asarray(vc[s]))
         # message-shaped: one session, a block of tokens
         km, vm = g.kv([5], [448], 64)
-        kc, vc = g.session_kv(5, 1024)
+        kc, vc = g.session_state(5, 1024)
         assert np.array_equal(np.asarray(km[0]), np.asarray(kc[448:512]))
         q1 = g.q([3, 5], [100, 517])
         q2 = g.q([5, 3, 3, 3, 3, 3, 3, 3], [517] + [100] * 7)

@@ -22,7 +22,7 @@ EXISTING = ("megastep_ms", "attend_roofline.decode", "step_mfu",
 
 
 def _run(events, attends):
-    geo = work.Geometry(16, 2, 8, 128, 24)
+    geo = spec.kind("gqa").Geometry(16, 2, 8, 128, 24)
     peaks = {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12}
     rec = Record(t0=0.0, t_end=1.0, tokens=8, steps=1, attends=attends,
                  appended=8 * len(attends))

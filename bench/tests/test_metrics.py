@@ -3,13 +3,13 @@ with censored turns included, and the readers' lookup by name."""
 
 import pytest
 
-from harness import spec, stats, work
+from harness import spec, stats
 from harness.cell import Run
 from harness.runner import Record
 
 
 def _run(rec, **kw):
-    geo = work.Geometry(16, 2, 8, 128, 24)
+    geo = spec.kind("gqa").Geometry(16, 2, 8, 128, 24)
     peaks = {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12}
     base = dict(cell="x", geo=geo, peaks=peaks, rec=rec, setup_s=12.5,
                 memory_peak_bytes=7_000_000_000,

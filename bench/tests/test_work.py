@@ -2,9 +2,9 @@
 
 import pytest
 
-from harness import work
+from harness import spec, work
 
-G = work.Geometry(page=16, lanes=2, n_kv=8, d=128, hq=24)
+G = spec.kind("gqa").Geometry(page=16, lanes=2, n_kv=8, d=128, hq=24)
 PEAKS = {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12}
 
 
@@ -16,17 +16,17 @@ def test_sizes():
 
 def test_all_raw_reads_every_page_with_its_strip():
     # 100 tokens = 7 pages (6 full + 1 partial), nothing packed
-    assert work.seq_bytes(100, 0.0, G) == 7 * (G.slot_bytes + G.strip_bytes)
+    assert G.seq_bytes(100, 0.0) == 7 * (G.slot_bytes + G.strip_bytes)
 
 
 def test_all_packed_reads_one_slot_per_complete_group():
     # 7 pages: 3 complete pairs packed + the partial 7th page raw
-    assert work.seq_bytes(100, 1.0, G) == 4 * (G.slot_bytes + G.strip_bytes)
+    assert G.seq_bytes(100, 1.0) == 4 * (G.slot_bytes + G.strip_bytes)
 
 
 def test_half_packed_is_between():
-    raw, packed = work.seq_bytes(128, 0.0, G), work.seq_bytes(128, 1.0, G)
-    assert work.seq_bytes(128, 0.5, G) == pytest.approx((raw + packed) / 2)
+    raw, packed = G.seq_bytes(128, 0.0), G.seq_bytes(128, 1.0)
+    assert G.seq_bytes(128, 0.5) == pytest.approx((raw + packed) / 2)
 
 
 def test_flops_and_bound():
